@@ -55,6 +55,29 @@ __all__ = [
 logger = logging.getLogger("repro.parallel")
 
 
+def _holds_accelerator() -> bool:
+    """Whether this process has brought up a JAX backend other than the CPU
+    (on a TPU host it then holds the chip). Initialises nothing."""
+    xb = sys.modules.get("jax._src.xla_bridge")
+    if xb is None or not xb.backends_are_initialized():
+        return False
+    return any(platform != "cpu" for platform in xb.backends())
+
+
+def _mp_context():
+    """Start method for workers and the event-queue manager.
+
+    A forked child inherits its parent's accelerator runtime and threads,
+    so a caller that holds an accelerator gets workers started fresh
+    ("spawn"). Others keep the platform default (fork on Linux), whose
+    workers start in milliseconds, not the second or so a fresh
+    interpreter takes to import the simulator.
+    """
+    if _holds_accelerator():
+        return multiprocessing.get_context("spawn")
+    return multiprocessing.get_context()
+
+
 def resolve_workers(workers: Union[int, str, None]) -> int:
     """Normalize a `workers=` argument to a concrete process count.
 
@@ -339,7 +362,9 @@ def parallel_map(
     size = resolve_chunk(chunk, len(tasks), n)
     groups = [tasks[i:i + size] for i in range(0, len(tasks), size)]
     try:
-        with ProcessPoolExecutor(max_workers=min(n, len(groups))) as pool:
+        with ProcessPoolExecutor(
+            max_workers=min(n, len(groups)), mp_context=_mp_context()
+        ) as pool:
             futures = [pool.submit(_run_chunk, fn, g) for g in groups]
             return [r for f in futures for r in f.result()]
     except (OSError, PermissionError, BrokenProcessPool) as exc:
@@ -370,7 +395,7 @@ def _monitored_map(
     """
     mon = _Monitor(monitor)
     try:
-        manager = multiprocessing.Manager()
+        manager = _mp_context().Manager()
     except Exception as exc:  # no subprocess/semaphore support here
         logger.warning("event queue unavailable (%s); running serially", exc)
         return _serial_map(fn, tasks, monitor, timeout_s is not None, tries)
@@ -390,7 +415,7 @@ def _monitored_map(
                       for i in range(0, len(tasks), size)]
             bases = list(range(0, len(tasks), size))
             with ProcessPoolExecutor(
-                max_workers=min(n_workers, len(groups))
+                max_workers=min(n_workers, len(groups)), mp_context=_mp_context()
             ) as pool:
                 futures = [
                     pool.submit(_run_chunk_monitored, mt, g, b)
@@ -439,7 +464,9 @@ def _resilient_map(
     emitted on those transitions.
     """
     results: List = [None] * len(tasks)
-    pool = ProcessPoolExecutor(max_workers=min(n_workers, len(tasks)))
+    pool = ProcessPoolExecutor(
+        max_workers=min(n_workers, len(tasks)), mp_context=_mp_context()
+    )
     abandoned = False
 
     def submit(i: int):

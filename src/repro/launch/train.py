@@ -19,6 +19,7 @@ import jax
 from ..configs import get_config
 from ..models import RuntimeFlags, build_model
 from ..training import AdamWConfig, DataConfig, train_loop
+from .compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -34,6 +35,7 @@ def main() -> None:
                     help="use the full config (needs a real cluster)")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch, smoke=not args.full_size)
     if not args.full_size:
         cfg = dataclasses.replace(cfg, dtype="float32")

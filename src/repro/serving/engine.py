@@ -59,6 +59,7 @@ class GenRequest:
     max_new_tokens: int
     eos_token: Optional[int] = None
     sampling: SamplingParams = SamplingParams()
+    keep_logits: bool = False  # keep each step's (V,) logits on the result
 
 
 @dataclasses.dataclass
@@ -67,6 +68,8 @@ class GenResult:
     tokens: List[int]
     prefill_s: float = 0.0
     decode_s: float = 0.0
+    # (V,) device arrays, prefill first, when the request set keep_logits
+    logits: List[jax.Array] = dataclasses.field(default_factory=list)
 
     @property
     def n_tokens(self) -> int:
@@ -176,9 +179,10 @@ class InferenceEngine:
         self.last_tok = self.last_tok.at[slot].set(tok)
         self._slot_req[slot] = req
         self._remaining[slot] = req.max_new_tokens - 1
-        self.results[req.uid] = GenResult(
-            req.uid, [tok], prefill_s=time.perf_counter() - t0
-        )
+        res = GenResult(req.uid, [tok], prefill_s=time.perf_counter() - t0)
+        if req.keep_logits:
+            res.logits.append(logits[0])
+        self.results[req.uid] = res
         if self._remaining[slot] <= 0 or tok == req.eos_token:
             self._finish(slot)
         return slot
@@ -206,6 +210,8 @@ class InferenceEngine:
                     len(self.results[req.uid].tokens),
                 )
                 nxt = nxt.at[slot].set(t)
+        # JAX returns before the device finishes: time the step's results
+        jax.block_until_ready((nxt, self._cache))
         dt = time.perf_counter() - t0
         self.pos = self.pos + jnp.asarray(
             [1 if a else 0 for a in self.active], jnp.int32
@@ -221,6 +227,8 @@ class InferenceEngine:
             res = self.results[req.uid]
             res.tokens.append(tok)
             res.decode_s += dt
+            if req.keep_logits:
+                res.logits.append(logits[slot])
             self._remaining[slot] -= 1
             if self._remaining[slot] <= 0 or tok == req.eos_token:
                 self._finish(slot)

@@ -42,15 +42,6 @@ def smoke_model(name: str, **rt_kw):
     return _model_cache[key]
 
 
-def abstract_mesh(sizes, names):
-    """jax.sharding.AbstractMesh across the 0.4/0.5 signature change:
-    new style is (sizes, names); jax < 0.5 takes ((name, size), ...)."""
-    try:
-        return jax.sharding.AbstractMesh(sizes, names)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(names, sizes)))
-
-
 def sample_inputs(model, batch=2, seq=12, extra=0, key=0):
     """(inputs-for-forward, labels) matching the arch's input modality."""
     cfg = model.cfg

@@ -6,7 +6,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from conftest import abstract_mesh
 import numpy as np
 import pytest
 pytest.importorskip("hypothesis")
@@ -194,7 +193,7 @@ class TestSharding:
         import numpy as _np
         devs = _np.array(jax.devices() * 4).reshape(2, 2)[:1, :1]
         # single-device container: simulate with AbstractMesh
-        mesh = abstract_mesh((2, 2), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((2, 2), ("data", "model"))
         ctx = sh._Ctx(mesh, sh.TRAIN_RULES)
         used = set()
         # dim 7 not divisible by model=2 -> replicated
@@ -205,7 +204,7 @@ class TestSharding:
     def test_axis_used_once(self):
         from repro import sharding as sh
 
-        mesh = abstract_mesh((2, 2), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((2, 2), ("data", "model"))
         ctx = sh._Ctx(mesh, sh.TRAIN_RULES)
         used = set()
         a = sh._resolve_dim(8, "ffn", ctx, used)

@@ -10,6 +10,7 @@ sweep equality.
 """
 
 import math
+import sys
 
 import numpy as np
 import pytest
@@ -24,6 +25,7 @@ from repro.core.latency_model import (
     LatencyModel,
     ModelService,
 )
+from repro.core.parallel import parallel_map
 from repro.core.simulator import SCHEMES, SimConfig, SimResult, SlotEngine, simulate
 from repro.network import NetSimConfig, SCENARIOS, simulate_network, three_cell_hetero
 
@@ -278,7 +280,30 @@ def _sat_point(lam: float, seed_idx: int) -> SimResult:
     return simulate(SCHEMES["icc"], cfg, SVC)
 
 
+def _jax_backend_inherited() -> bool:
+    xb = sys.modules.get("jax._src.xla_bridge")
+    return xb is not None and xb.backends_are_initialized()
+
+
 class TestParallelSweeps:
+    @pytest.mark.parametrize(
+        "kw", [{}, {"heartbeat_s": 5.0}, {"task_timeout_s": 60.0}],
+        ids=["pooled", "monitored", "resilient"],
+    )
+    def test_accelerator_holder_does_not_fork(self, monkeypatch, kw):
+        """A caller holding an accelerator (on a TPU host: the chip) gets
+        workers started fresh, not forks that inherit its JAX runtime."""
+        import jax
+
+        import repro.core.parallel as par
+
+        jax.devices()
+        assert _jax_backend_inherited()
+        assert not par._holds_accelerator()  # the CPU backend holds no chip
+        monkeypatch.setattr(par, "_holds_accelerator", lambda: True)
+        got = parallel_map(_jax_backend_inherited, [()] * 2, workers=2, **kw)
+        assert got == [False, False]
+
     def test_parallel_equals_serial_generic(self):
         rates = [5.0, 20.0]
         serial = sweep_generic(rates, _sat_point, n_seeds=2, workers=0)

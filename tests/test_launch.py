@@ -2,13 +2,15 @@
 skip rules, roofline math. The actual 512-device lower/compile runs live
 in repro.launch.dryrun (results under benchmarks/results/dryrun)."""
 
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 
-from conftest import abstract_mesh
 import pytest
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.roofline import V5E, derive_roofline, model_flops
 from repro.launch.hlo_analysis import HloCost
 from repro.launch.specs import SHAPES, build_case, skip_reason
@@ -106,8 +108,26 @@ class TestDecodeRulesV3:
     def test_embed_sharded_over_data(self):
         from repro import sharding as sh
 
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
         ctx = sh._Ctx(mesh, sh.DECODE_RULES_V3)
         assert sh._resolve_dim(8192, "embed", ctx, set()) == "data"
         # batch stays replicated in V2/V3
         assert sh._resolve_dim(128, "batch", ctx, set()) is None
+
+
+class TestCompileCache:
+    def test_env_dir_is_left_alone(self, monkeypatch, tmp_path):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_fixed_in_the_checkout(self, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        want = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+        try:
+            assert use_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)

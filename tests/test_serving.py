@@ -2,6 +2,7 @@
 priority admission and deadline drops."""
 
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +15,7 @@ from repro.serving import (
     ICCRequest,
     ICCServer,
     InferenceEngine,
+    measure_service_time,
 )
 
 _CACHE = {}
@@ -51,6 +53,36 @@ class TestEngine:
         out = eng.generate([mk_req(i, new=3) for i in range(6)])
         assert len(out) == 6
         assert all(len(r.tokens) == 3 for r in out.values())
+
+    def test_decode_s_waits_for_the_device(self, monkeypatch):
+        """JAX returns before the device finishes: each decode step's time
+        must cover a block_until_ready of its results."""
+        m, p = model_params()
+        InferenceEngine(m, p, max_batch=2, max_seq=48).generate([mk_req(0)])
+        wait = 0.05
+        ready = jax.block_until_ready
+
+        def slow_ready(x):
+            time.sleep(wait)
+            return ready(x)
+
+        monkeypatch.setattr(jax, "block_until_ready", slow_ready)
+        eng = InferenceEngine(m, p, max_batch=2, max_seq=48)
+        res = eng.generate([mk_req(1, new=4)])[1]
+        assert res.decode_s >= 3 * wait  # three decode steps after prefill
+        cal = measure_service_time(m, p, 10, 4, max_seq=48, repeats=1)
+        assert cal["decode_s"] >= 3 * wait
+
+    def test_keep_logits(self):
+        m, p = model_params()
+        eng = InferenceEngine(m, p, max_batch=2, max_seq=48)
+        reqs = [mk_req(0, new=4), dataclasses.replace(mk_req(1, new=4),
+                                                      keep_logits=True)]
+        out = eng.generate(reqs)
+        assert out[0].logits == []
+        kept = out[1].logits
+        assert len(kept) == 4 and kept[0].shape == (m.cfg.padded_vocab,)
+        assert [int(jnp.argmax(row)) for row in kept] == out[1].tokens
 
     def test_reset_clears_state(self):
         m, p = model_params()
