@@ -7,6 +7,12 @@ satisfaction/latency stats.
 
   PYTHONPATH=src python -m repro.launch.serve --rate 20     # smoke, float32
   PYTHONPATH=src python -m repro.launch.serve --full-size   # registered config
+  PYTHONPATH=src python -m repro.launch.serve --trace out.json
+
+`--trace PATH` records each policy's served requests (lifecycle, stage
+breakdown, per-call host and CPU time) and writes one Chrome trace per
+policy, named PATH with the policy before its suffix (`out.priority.json`,
+`out.fifo.json`); open them at https://ui.perfetto.dev.
 
 `--full-size` serves the registered config in its own dtype; it needs an
 accelerator that holds the whole model (`chip_smoke.py` serves one chip's
@@ -17,6 +23,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
 import time
 from typing import Dict, List, Optional, Sequence
 
@@ -36,6 +43,7 @@ from ..serving import (
     ServeStats,
 )
 from ..serving.calibrate import measure_service_time
+from ..telemetry import EventRecorder, write_chrome_trace
 from .compile_cache import use_compile_cache
 
 
@@ -105,8 +113,10 @@ def serve(
     max_batch: int = 8,
     max_seq: Optional[int] = None,  # None -> n_input + n_output + 8
     keep_logits: bool = False,
+    trace_path: Optional[str] = None,
 ) -> ServeReport:
-    """Serve one seeded trace of `cfg` under each policy and print stats."""
+    """Serve one seeded trace of `cfg` under each policy and print stats;
+    with `trace_path`, write each policy's Chrome trace (module doc)."""
     model = build_model(cfg, RuntimeFlags(remat=False))
     t0 = time.perf_counter()
     params = jax.block_until_ready(init_params(model))
@@ -125,10 +135,19 @@ def serve(
         t0 = time.perf_counter()
         eng.warmup(trace[0].req.prompt)
         warmup_s = time.perf_counter() - t0
-        srv = ICCServer(eng, policy=policy, est_latency=cal["total_s"])
+        # every engine call sampled: calls are never closer than 1 us
+        rec = EventRecorder(sample_every_s=1e-6) if trace_path else None
+        srv = ICCServer(eng, policy=policy, est_latency=cal["total_s"],
+                        recorder=rec)
         t0 = time.perf_counter()
         stats = srv.run(trace)
         serve_s = time.perf_counter() - t0
+        if rec is not None:
+            root, ext = os.path.splitext(trace_path)
+            path = f"{root}.{policy}{ext or '.json'}"
+            write_chrome_trace(rec.to_telemetry(
+                meta={"arch": cfg.name, "policy": policy}), path)
+            print(f"[serve] {policy}: trace written to {path}")
         e2e = np.array(stats.e2e) if stats.e2e else np.array([np.nan])
         print(
             f"[serve] {policy:8s}: {stats.n_total} reqs, "
@@ -153,6 +172,8 @@ def main() -> None:
     ap.add_argument("--full-size", action="store_true",
                     help="registered config in its own dtype (needs a chip "
                          "that holds it)")
+    ap.add_argument("--trace", metavar="PATH",
+                    help="write a Chrome trace of each policy's requests")
     args = ap.parse_args()
 
     use_compile_cache()
@@ -161,7 +182,7 @@ def main() -> None:
         cfg = dataclasses.replace(cfg, dtype="float32")
     serve(cfg, rate=args.rate, duration=args.duration, n_input=args.n_input,
           n_output=args.n_output, budget=args.budget,
-          max_batch=args.max_batch)
+          max_batch=args.max_batch, trace_path=args.trace)
 
 
 if __name__ == "__main__":

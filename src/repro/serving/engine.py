@@ -7,6 +7,14 @@ This is the standard continuous-batching serving loop (Orca-style), sized
 for CPU smoke models here and for the sharded meshes via the same jitted
 functions.
 
+Every `submit` and `step` runs inside `jax.profiler.TraceAnnotation`
+spans (`engine.prefill` with its children `.dispatch`, `.sync`,
+`engine.splice` and `.update`; `engine.step` with `.dispatch`, `.sync`,
+`.update` and `.readback`). They are written into the profiler's trace,
+on the device's clock, only while a profiler runs, and cost well under a
+microsecond each otherwise. `InferenceEngine.counters` counts steps,
+prefills, active slots stepped and device-to-host syncs.
+
 Slot splicing is generic across cache families (attention KV, Mamba/xLSTM
 states, enc-dec cross KV): the logical-axes tree from `model.init_cache`
 marks each leaf's batch dim ("kv_batch"), so insertion is a
@@ -21,10 +29,14 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..models.model import Model
 
-__all__ = ["GenRequest", "GenResult", "InferenceEngine", "SamplingParams"]
+__all__ = [
+    "EngineCounters", "GenRequest", "GenResult", "InferenceEngine",
+    "SamplingParams",
+]
 
 
 def sample_token(
@@ -76,6 +88,17 @@ class GenResult:
         return len(self.tokens)
 
 
+@dataclasses.dataclass
+class EngineCounters:
+    """What the engine did since its last `reset()`."""
+
+    steps: int = 0
+    prefills: int = 0
+    slot_steps: int = 0  # active slots summed over steps
+    # block_until_ready calls and int() reads of device values in steps
+    step_host_syncs: int = 0
+
+
 class InferenceEngine:
     def __init__(
         self,
@@ -102,20 +125,22 @@ class InferenceEngine:
         self.results: Dict[int, GenResult] = {}
         self._slot_req: List[Optional[GenRequest]] = [None] * max_batch
         self._remaining = [0] * max_batch
+        self.counters = EngineCounters()
 
         self._decode = jax.jit(model.decode)
         self._prefill = jax.jit(model.prefill)
 
     # ------------------------------------------------------------- slots
     def reset(self) -> None:
-        """Clear all slots and results (cache contents become irrelevant:
-        slot positions mark everything invalid)."""
+        """Clear all slots, results and counters (cache contents become
+        irrelevant: slot positions mark everything invalid)."""
         self.active = [False] * self.M
         self.pos = jnp.zeros((self.M,), jnp.int32)
         self.last_tok = jnp.zeros((self.M,), jnp.int32)
         self.results = {}
         self._slot_req = [None] * self.M
         self._remaining = [0] * self.M
+        self.counters = EngineCounters()
         cache, _ = self.model.init_cache(
             self.M, self.Sc, enc_len=self._enc_len
         )
@@ -164,27 +189,33 @@ class InferenceEngine:
         if not slots:
             raise RuntimeError("no free slot")
         slot = slots[0]
-        t0 = time.perf_counter()
-        if isinstance(req.prompt, dict):
-            prompt = {k: v[None] for k, v in req.prompt.items()}
-            plen = prompt["dec_tokens"].shape[1]
-        else:
-            prompt = req.prompt[None]
-            plen = prompt.shape[1]
-        logits, cache1 = self._prefill(self.params, prompt)
-        tok = int(sample_token(logits[0], req.sampling, req.uid, 0))
-        self._splice(cache1, slot, plen)
-        self.active[slot] = True
-        self.pos = self.pos.at[slot].set(plen)
-        self.last_tok = self.last_tok.at[slot].set(tok)
-        self._slot_req[slot] = req
-        self._remaining[slot] = req.max_new_tokens - 1
-        res = GenResult(req.uid, [tok], prefill_s=time.perf_counter() - t0)
-        if req.keep_logits:
-            res.logits.append(logits[0])
-        self.results[req.uid] = res
-        if self._remaining[slot] <= 0 or tok == req.eos_token:
-            self._finish(slot)
+        with TraceAnnotation("engine.prefill"):
+            t0 = time.perf_counter()
+            if isinstance(req.prompt, dict):
+                prompt = {k: v[None] for k, v in req.prompt.items()}
+                plen = prompt["dec_tokens"].shape[1]
+            else:
+                prompt = req.prompt[None]
+                plen = prompt.shape[1]
+            with TraceAnnotation("engine.prefill.dispatch"):
+                logits, cache1 = self._prefill(self.params, prompt)
+            with TraceAnnotation("engine.prefill.sync"):
+                tok = int(sample_token(logits[0], req.sampling, req.uid, 0))
+            with TraceAnnotation("engine.splice"):
+                self._splice(cache1, slot, plen)
+            with TraceAnnotation("engine.prefill.update"):
+                self.pos = self.pos.at[slot].set(plen)
+                self.last_tok = self.last_tok.at[slot].set(tok)
+            self.active[slot] = True
+            self._slot_req[slot] = req
+            self._remaining[slot] = req.max_new_tokens - 1
+            res = GenResult(req.uid, [tok], prefill_s=time.perf_counter() - t0)
+            if req.keep_logits:
+                res.logits.append(logits[0])
+            self.results[req.uid] = res
+            if self._remaining[slot] <= 0 or tok == req.eos_token:
+                self._finish(slot)
+            self.counters.prefills += 1
         return slot
 
     def _finish(self, slot: int) -> None:
@@ -196,42 +227,54 @@ class InferenceEngine:
         """One lock-step decode tick for all active slots. Returns #active."""
         if self.n_active == 0:
             return 0
-        t0 = time.perf_counter()
-        logits, self._cache = self._decode(
-            self.params, self._cache, self.last_tok, self.pos
-        )
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        # per-slot stochastic sampling where requested (greedy is fused)
-        for slot in range(self.M):
-            req = self._slot_req[slot]
-            if req is not None and req.sampling.temperature > 0.0:
-                t = sample_token(
-                    logits[slot], req.sampling, req.uid,
-                    len(self.results[req.uid].tokens),
+        with TraceAnnotation("engine.step"):
+            t0 = time.perf_counter()
+            with TraceAnnotation("engine.step.dispatch"):
+                logits, self._cache = self._decode(
+                    self.params, self._cache, self.last_tok, self.pos
                 )
-                nxt = nxt.at[slot].set(t)
-        # JAX returns before the device finishes: time the step's results
-        jax.block_until_ready((nxt, self._cache))
-        dt = time.perf_counter() - t0
-        self.pos = self.pos + jnp.asarray(
-            [1 if a else 0 for a in self.active], jnp.int32
-        )
-        self.last_tok = jnp.where(
-            jnp.asarray(self.active), nxt, self.last_tok
-        )
-        for slot in range(self.M):
-            if not self.active[slot]:
-                continue
-            req = self._slot_req[slot]
-            tok = int(nxt[slot])
-            res = self.results[req.uid]
-            res.tokens.append(tok)
-            res.decode_s += dt
-            if req.keep_logits:
-                res.logits.append(logits[slot])
-            self._remaining[slot] -= 1
-            if self._remaining[slot] <= 0 or tok == req.eos_token:
-                self._finish(slot)
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                # per-slot stochastic sampling where requested (greedy is
+                # fused)
+                for slot in range(self.M):
+                    req = self._slot_req[slot]
+                    if req is not None and req.sampling.temperature > 0.0:
+                        t = sample_token(
+                            logits[slot], req.sampling, req.uid,
+                            len(self.results[req.uid].tokens),
+                        )
+                        nxt = nxt.at[slot].set(t)
+            # JAX returns before the device finishes: time the step's results
+            c = self.counters
+            with TraceAnnotation("engine.step.sync"):
+                jax.block_until_ready((nxt, self._cache))
+                c.step_host_syncs += 1
+            dt = time.perf_counter() - t0
+            with TraceAnnotation("engine.step.update"):
+                self.pos = self.pos + jnp.asarray(
+                    [1 if a else 0 for a in self.active], jnp.int32
+                )
+                self.last_tok = jnp.where(
+                    jnp.asarray(self.active), nxt, self.last_tok
+                )
+            n_stepped = self.n_active
+            with TraceAnnotation("engine.step.readback"):
+                for slot in range(self.M):
+                    if not self.active[slot]:
+                        continue
+                    req = self._slot_req[slot]
+                    tok = int(nxt[slot])
+                    c.step_host_syncs += 1
+                    res = self.results[req.uid]
+                    res.tokens.append(tok)
+                    res.decode_s += dt
+                    if req.keep_logits:
+                        res.logits.append(logits[slot])
+                    self._remaining[slot] -= 1
+                    if self._remaining[slot] <= 0 or tok == req.eos_token:
+                        self._finish(slot)
+            c.steps += 1
+            c.slot_steps += n_stepped
         return self.n_active
 
     def generate(self, reqs: List[GenRequest]) -> Dict[int, GenResult]:

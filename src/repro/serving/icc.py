@@ -12,6 +12,17 @@ in §IV-B. `policy="fifo"` gives the 5G-MEC baseline.
 Time base: a virtual clock driven by *measured* engine latencies, so the
 scheduling dynamics are real compute dynamics (on this host's CPU for
 smoke models; identical code paths on a TPU mesh).
+
+Tracing: admission and reaping run inside `jax.profiler.TraceAnnotation`
+spans (`icc.admit`, `icc.reap`), beside the engine's own. A ``recorder=``
+(`repro.telemetry`) receives each request's lifecycle on the server's
+clock (`generated`, `uplink_done` at arrival, `admit`, `prefill`,
+`decode`, `complete`, `drop`), so `EventRecorder.stage_breakdown` sums to
+the request's `ServeStats.e2e`: the UE-to-node `t_comm` books as radio,
+and `stall` is the time a resident request waited while others
+prefilled. It also samples, per engine call, the track `engine.prefill`
+or `engine.step` with the call's host seconds (`wall_s`) and the
+thread's CPU seconds across it (`cpu_s`).
 """
 
 from __future__ import annotations
@@ -21,8 +32,11 @@ import dataclasses
 import heapq
 import itertools
 import time
-from typing import Dict, List, Literal, Optional, Tuple
+from typing import Callable, Dict, List, Literal, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
+from ..telemetry.recorder import TraceRecorder, active
 from .engine import GenRequest, GenResult, InferenceEngine
 
 __all__ = ["ICCRequest", "ServeStats", "ICCServer"]
@@ -81,8 +95,10 @@ class ICCServer:
         policy: Literal["priority", "fifo"] = "priority",
         drop_infeasible: bool = True,
         est_latency: Optional[float] = None,  # predicted service time (s)
+        recorder: Optional[TraceRecorder] = None,
     ):
         self.engine = engine
+        self._rec = active(recorder)
         self.policy = policy
         self.drop_infeasible = drop_infeasible
         self.est_latency = est_latency
@@ -97,29 +113,69 @@ class ICCServer:
         heapq.heappush(self._queue, (key, next(self._seq), r))
         self.stats.n_total += 1
         self.stats.route_total[r.route] += 1
+        if self._rec is not None:
+            uid = r.req.uid
+            self._rec.job_event("generated", uid, r.t_gen)
+            self._rec.job_event("uplink_done", uid, r.arrival,
+                                t_arrival=r.arrival, route=r.route)
+
+    def _timed(self, kind: str, call: Callable[[], object]) -> float:
+        """Run one engine call and return its host seconds, by which it
+        advances the clock. With a recorder, the thread's CPU time across
+        the call is sampled on the track `kind`."""
+        if self._rec is None:
+            t0 = time.perf_counter()
+            call()
+            return time.perf_counter() - t0
+        cpu0 = time.thread_time()
+        t0 = time.perf_counter()
+        call()
+        dt = time.perf_counter() - t0
+        cpu = time.thread_time() - cpu0
+        self._rec.sample(kind, self.now, {"wall_s": dt, "cpu_s": cpu})
+        return dt
 
     def _admit(self) -> None:
-        while self._queue and self.engine.free_slots():
-            _, _, r = heapq.heappop(self._queue)
-            if self.drop_infeasible and self.est_latency is not None:
-                if self.now + self.est_latency > r.deadline:
-                    self.stats.n_dropped += 1
-                    continue
-            t0 = time.perf_counter()
-            self.engine.submit(r.req)
-            self.now += time.perf_counter() - t0  # prefill advances the clock
-            self._inflight[r.req.uid] = r
+        with TraceAnnotation("icc.admit"):
+            while self._queue and self.engine.free_slots():
+                _, _, r = heapq.heappop(self._queue)
+                uid = r.req.uid
+                if self.drop_infeasible and self.est_latency is not None:
+                    if self.now + self.est_latency > r.deadline:
+                        self.stats.n_dropped += 1
+                        if self._rec is not None:
+                            self._rec.job_event("drop", uid, self.now,
+                                                reason="infeasible")
+                        continue
+                if self._rec is not None:
+                    self._rec.job_event("admit", uid, self.now)
+                dt = self._timed("engine.prefill",
+                                 lambda: self.engine.submit(r.req))
+                self.now += dt  # prefill advances the clock
+                if self._rec is not None:
+                    self._rec.job_event("prefill", uid, self.now, dt=dt)
+                self._inflight[uid] = r
+
+    def _step(self) -> None:
+        uids = self.engine.active_uids() if self._rec is not None else ()
+        dt = self._timed("engine.step", self.engine.step)
+        self.now += dt
+        for uid in uids:
+            self._rec.job_event("decode", uid, self.now, dt=dt)
 
     def _reap(self) -> None:
-        active = set(self.engine.active_uids())
-        done = [uid for uid in self._inflight if uid not in active]
-        for uid in done:
-            r = self._inflight.pop(uid)
-            e2e = self.now - r.t_gen  # virtual clock shares t_gen's timeline
-            self.stats.e2e.append(e2e)
-            if e2e <= r.b_total:
-                self.stats.n_satisfied += 1
-                self.stats.route_satisfied[r.route] += 1
+        with TraceAnnotation("icc.reap"):
+            active_uids = set(self.engine.active_uids())
+            done = [uid for uid in self._inflight if uid not in active_uids]
+            for uid in done:
+                r = self._inflight.pop(uid)
+                e2e = self.now - r.t_gen  # virtual clock shares t_gen's timeline
+                self.stats.e2e.append(e2e)
+                if e2e <= r.b_total:
+                    self.stats.n_satisfied += 1
+                    self.stats.route_satisfied[r.route] += 1
+                if self._rec is not None:
+                    self._rec.job_event("complete", uid, self.now)
 
     def run(self, requests: List[ICCRequest]) -> ServeStats:
         """Drive the event loop over a pre-generated arrival trace."""
@@ -132,9 +188,7 @@ class ICCServer:
                 i += 1
             self._admit()
             if self.engine.n_active:
-                t0 = time.perf_counter()
-                self.engine.step()
-                self.now += time.perf_counter() - t0
+                self._step()
             elif i < len(pending):
                 self.now = max(self.now, pending[i].arrival)  # idle-skip
             self._reap()

@@ -6,17 +6,20 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_config
 from repro.models import RuntimeFlags, build_model
 from repro.serving import (
+    EngineCounters,
     GenRequest,
     ICCRequest,
     ICCServer,
     InferenceEngine,
     measure_service_time,
 )
+from repro.telemetry import EventRecorder
 
 _CACHE = {}
 
@@ -93,6 +96,22 @@ class TestEngine:
         out = eng.generate([mk_req(1, new=2)])
         assert len(out[1].tokens) == 2
 
+    def test_counters_after_a_known_generate(self):
+        """max_batch 2, outputs of 3, 5 and 2 tokens: r0 and r1 step twice
+        together, r2 takes r0's slot and steps once beside r1, r1 steps
+        once alone: 4 steps of 2, 2, 2 and 1 active slots."""
+        m, p = model_params()
+        eng = InferenceEngine(m, p, max_batch=2, max_seq=48)
+        reqs = [mk_req(0, new=3), mk_req(1, new=5), mk_req(2, new=2)]
+        eng.generate(reqs)
+        assert eng.counters == EngineCounters(
+            steps=4, prefills=3, slot_steps=7,
+            step_host_syncs=4 + 7,  # a block_until_ready and an int() per slot
+        )
+        assert eng.counters.slot_steps == sum(r.max_new_tokens - 1 for r in reqs)
+        eng.reset()
+        assert eng.counters == EngineCounters()
+
     def test_recurrent_arch_engine(self):
         """Continuous batching over a state-cache arch (zamba2)."""
         m, p = model_params("zamba2-7b")
@@ -135,6 +154,68 @@ class TestICCServer:
         srv = ICCServer(eng, policy="priority", est_latency=10.0)
         stats = srv.run(self._trace(4, b_total=0.001))
         assert stats.n_dropped == 4
+
+    def _mixed_trace(self):
+        """Every third request cannot meet its budget: est_latency 1 s
+        drops it at admission whatever the host's speed."""
+        return [
+            ICCRequest(mk_req(i, new=2 + i % 3), t_gen=0.002 * i, t_comm=0.01,
+                       b_total=0.001 if i % 3 == 2 else 60.0)
+            for i in range(8)
+        ]
+
+    def _serve(self, trace, recorder=None):
+        m, p = model_params()
+        eng = InferenceEngine(m, p, max_batch=2, max_seq=48)
+        eng.warmup(mk_req(0).prompt)
+        srv = ICCServer(eng, policy="priority", est_latency=1.0,
+                        recorder=recorder)
+        return srv.run(trace), eng
+
+    def test_recorder_stages_telescope_to_e2e(self):
+        trace = self._mixed_trace()
+        rec = EventRecorder(sample_every_s=1e-9)
+        stats, eng = self._serve(trace, rec)
+        served = [r for r in trace if r.req.uid in eng.results]
+        assert len(served) == len(stats.e2e) == 6
+        e2e = {}
+        for r in served:
+            st = rec.stage_breakdown(r.req.uid)
+            assert st["radio"] == pytest.approx(r.t_comm, abs=1e-12)
+            assert st["transport"] == 0.0
+            assert st["queue"] >= 0.0 and st["stall"] >= -1e-12
+            e2e[r.req.uid] = sum(st.values())
+        np.testing.assert_allclose(sorted(e2e.values()), sorted(stats.e2e),
+                                   rtol=0, atol=1e-9)
+        # a resident request waited while the other slot's requests
+        # prefilled
+        assert max(rec.stage_breakdown(u)["stall"] for u in e2e) > 0.0
+        # one sample per engine call, none throttled away
+        c = eng.counters
+        assert len(rec.series["engine.step"]["wall_s"]) == c.steps
+        assert len(rec.series["engine.prefill"]["wall_s"]) == c.prefills == 6
+        for track in ("engine.step", "engine.prefill"):
+            s = rec.series[track]
+            assert all(v > 0.0 for v in s["wall_s"])
+            assert all(v >= 0.0 for v in s["cpu_s"])
+            assert set(s) == {"t", "wall_s", "cpu_s"}
+
+    def test_recorder_drops_carry_the_reason(self):
+        rec = EventRecorder(sample_every_s=1e-9)
+        stats, _ = self._serve(self._mixed_trace(), rec)
+        assert stats.n_dropped == 2
+        assert rec.drop_reason_counts() == {"infeasible": stats.n_dropped}
+
+    def test_recorder_changes_no_result(self):
+        plain_stats, plain = self._serve(self._mixed_trace())
+        rec_stats, traced = self._serve(self._mixed_trace(), EventRecorder())
+        assert {u: r.tokens for u, r in plain.results.items()} == \
+            {u: r.tokens for u, r in traced.results.items()}
+        for f in ("n_total", "n_satisfied", "n_dropped", "route_total",
+                  "route_satisfied"):
+            assert getattr(plain_stats, f) == getattr(rec_stats, f), f
+        assert len(plain_stats.e2e) == len(rec_stats.e2e)
+        assert plain.counters == traced.counters
 
     def test_priority_orders_by_slack(self):
         a = ICCRequest(mk_req(0), t_gen=0.0, t_comm=0.05, b_total=0.08)
@@ -228,3 +309,25 @@ class TestEngineAllArchs:
         eng = InferenceEngine(m, p, max_batch=2, max_seq=24, enc_len=10)
         out = eng.generate(reqs)
         assert all(len(r.tokens) == 3 for r in out.values())
+
+
+def test_launch_serve_writes_a_chrome_trace(tmp_path):
+    """`launch/serve.py --trace PATH`: one Chrome trace per policy, with a
+    span group per served request and the engine's per-call tracks."""
+    import json
+
+    from repro.launch.serve import serve
+
+    cfg = dataclasses.replace(get_config("llama2-7b", smoke=True),
+                              dtype="float32")
+    rep = serve(cfg, policies=("priority",), rate=20.0, duration=0.2,
+                n_input=6, n_output=3, budget=60.0, max_batch=2,
+                trace_path=str(tmp_path / "out.json"))
+    with open(tmp_path / "out.priority.json") as f:
+        tr = json.load(f)
+    served = rep.runs[0].results
+    assert served
+    names = {e.get("name") for e in tr["traceEvents"]}
+    assert "engine.step" in names and "engine.prefill" in names
+    jobs = {int(e["id"]) for e in tr["traceEvents"] if e.get("cat") == "job"}
+    assert jobs == set(served)
