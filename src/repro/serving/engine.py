@@ -2,18 +2,26 @@
 
 A fixed pool of `max_batch` decode slots over one batched cache; requests
 are prefill'ed individually (batch-1) and spliced into a free slot, decode
-advances all active slots in lock-step (one fused `decode_step` per tick).
-This is the standard continuous-batching serving loop (Orca-style), sized
-for CPU smoke models here and for the sharded meshes via the same jitted
-functions.
+advances all active slots in lock-step. This is the standard
+continuous-batching serving loop (Orca-style), sized for CPU smoke models
+here and for the sharded meshes via the same jitted functions.
+
+The slot bookkeeping (`active`, `pos`, `last_tok`) lives on the host as
+numpy arrays. A decode tick is one compiled program, `decode_step`: the
+model's decode and each slot's greedy token. After one wait for it, the
+step reads all slots' tokens in one device-to-host copy. Only a slot whose
+request samples (`temperature > 0`) leaves that path: `sample_token` draws
+its token from its logits, and the host reads it on its own. So a greedy
+step makes two host syncs whatever the slot count.
 
 Every `submit` and `step` runs inside `jax.profiler.TraceAnnotation`
 spans (`engine.prefill` with its children `.dispatch`, `.sync`,
 `engine.splice` and `.update`; `engine.step` with `.dispatch`, `.sync`,
-`.update` and `.readback`). They are written into the profiler's trace,
-on the device's clock, only while a profiler runs, and cost well under a
-microsecond each otherwise. `InferenceEngine.counters` counts steps,
-prefills, active slots stepped and device-to-host syncs.
+`.readback` and `.update`; both `.update` spans are host work only).
+They are written into the profiler's trace, on the device's clock, only
+while a profiler runs, and cost well under a microsecond each otherwise. `InferenceEngine.counters` counts steps,
+prefills, active slots stepped, device-to-host syncs and slot-steps that
+took the host sampling path.
 
 Slot splicing is generic across cache families (attention KV, Mamba/xLSTM
 states, enc-dec cross KV): the logical-axes tree from `model.init_cache`
@@ -24,11 +32,13 @@ marks each leaf's batch dim ("kv_batch"), so insertion is a
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.profiler import TraceAnnotation
 
 from ..models.model import Model
@@ -55,6 +65,19 @@ def sample_token(
         choice = jax.random.categorical(key, vals)
         return idx[choice].astype(jnp.int32)
     return jax.random.categorical(key, scaled).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def decode_step(
+    model: Model, params: dict, cache: dict, tokens: jax.Array,
+    pos: jax.Array,
+):
+    """One decode tick as one program: the model's decode and each slot's
+    greedy token. -> ((logits (B, V), next tokens (B,) int32), cache), the
+    cache second as in `Model.decode`. The benchmark finds the program in
+    the device trace by its name, `jit_decode_step`."""
+    logits, cache = model.decode(params, cache, tokens, pos)
+    return (logits, jnp.argmax(logits, axis=-1).astype(jnp.int32)), cache
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,8 +118,10 @@ class EngineCounters:
     steps: int = 0
     prefills: int = 0
     slot_steps: int = 0  # active slots summed over steps
-    # block_until_ready calls and int() reads of device values in steps
+    # block_until_ready calls and reads of device values in steps
     step_host_syncs: int = 0
+    # slot-steps whose request samples, so whose token the host drew
+    sampled_slot_steps: int = 0
 
 
 class InferenceEngine:
@@ -118,29 +143,28 @@ class InferenceEngine:
         self._batch_axis = jax.tree.map(
             lambda ax: ax.index("kv_batch") if "kv_batch" in ax else 0, caxes
         )
-        # slot bookkeeping (host side)
-        self.active = [False] * max_batch
-        self.pos = jnp.zeros((max_batch,), jnp.int32)
-        self.last_tok = jnp.zeros((max_batch,), jnp.int32)
-        self.results: Dict[int, GenResult] = {}
-        self._slot_req: List[Optional[GenRequest]] = [None] * max_batch
-        self._remaining = [0] * max_batch
-        self.counters = EngineCounters()
-
-        self._decode = jax.jit(model.decode)
+        self._clear_slots()
+        # (params, cache, tokens, pos) -> ((logits, next tokens), cache)
+        self._decode = functools.partial(decode_step, model)
         self._prefill = jax.jit(model.prefill)
 
     # ------------------------------------------------------------- slots
+    def _clear_slots(self) -> None:
+        """Host-side slot bookkeeping, results and counters, all empty.
+        `pos` and `last_tok` keep fixed dtypes and shapes, so the decode
+        program compiles once."""
+        self.active = np.zeros((self.M,), bool)
+        self.pos = np.zeros((self.M,), np.int32)
+        self.last_tok = np.zeros((self.M,), np.int32)
+        self.results: Dict[int, GenResult] = {}
+        self._slot_req: List[Optional[GenRequest]] = [None] * self.M
+        self._remaining = [0] * self.M
+        self.counters = EngineCounters()
+
     def reset(self) -> None:
         """Clear all slots, results and counters (cache contents become
         irrelevant: slot positions mark everything invalid)."""
-        self.active = [False] * self.M
-        self.pos = jnp.zeros((self.M,), jnp.int32)
-        self.last_tok = jnp.zeros((self.M,), jnp.int32)
-        self.results = {}
-        self._slot_req = [None] * self.M
-        self._remaining = [0] * self.M
-        self.counters = EngineCounters()
+        self._clear_slots()
         cache, _ = self.model.init_cache(
             self.M, self.Sc, enc_len=self._enc_len
         )
@@ -154,7 +178,7 @@ class InferenceEngine:
         self.reset()
 
     def free_slots(self) -> List[int]:
-        return [i for i, a in enumerate(self.active) if not a]
+        return np.flatnonzero(~self.active).tolist()
 
     def active_uids(self) -> List[int]:
         """uids of the requests currently occupying decode slots."""
@@ -162,7 +186,7 @@ class InferenceEngine:
 
     @property
     def n_active(self) -> int:
-        return sum(self.active)
+        return int(self.active.sum())
 
     def _splice(self, cache1: dict, slot: int, prompt_len: int) -> None:
         """Insert a batch-1 prefill cache into slot `slot`."""
@@ -203,9 +227,9 @@ class InferenceEngine:
                 tok = int(sample_token(logits[0], req.sampling, req.uid, 0))
             with TraceAnnotation("engine.splice"):
                 self._splice(cache1, slot, plen)
-            with TraceAnnotation("engine.prefill.update"):
-                self.pos = self.pos.at[slot].set(plen)
-                self.last_tok = self.last_tok.at[slot].set(tok)
+            with TraceAnnotation("engine.prefill.update"):  # host only
+                self.pos[slot] = plen
+                self.last_tok[slot] = tok
             self.active[slot] = True
             self._slot_req[slot] = req
             self._remaining[slot] = req.max_new_tokens - 1
@@ -224,47 +248,46 @@ class InferenceEngine:
         self._remaining[slot] = 0
 
     def step(self) -> int:
-        """One lock-step decode tick for all active slots. Returns #active."""
+        """One lock-step decode tick for all active slots. Returns #active.
+
+        One compiled program (`decode_step`), one wait for it, one read of
+        every slot's token; the slot bookkeeping after it is host work."""
         if self.n_active == 0:
             return 0
+        c = self.counters
         with TraceAnnotation("engine.step"):
             t0 = time.perf_counter()
             with TraceAnnotation("engine.step.dispatch"):
-                logits, self._cache = self._decode(
+                (logits, nxt), self._cache = self._decode(
                     self.params, self._cache, self.last_tok, self.pos
                 )
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                # per-slot stochastic sampling where requested (greedy is
-                # fused)
-                for slot in range(self.M):
-                    req = self._slot_req[slot]
+                # slots whose request samples draw from their logits
+                sampled = {}
+                for slot, req in enumerate(self._slot_req):
                     if req is not None and req.sampling.temperature > 0.0:
-                        t = sample_token(
+                        sampled[slot] = sample_token(
                             logits[slot], req.sampling, req.uid,
                             len(self.results[req.uid].tokens),
                         )
-                        nxt = nxt.at[slot].set(t)
             # JAX returns before the device finishes: time the step's results
-            c = self.counters
             with TraceAnnotation("engine.step.sync"):
                 jax.block_until_ready((nxt, self._cache))
                 c.step_host_syncs += 1
             dt = time.perf_counter() - t0
-            with TraceAnnotation("engine.step.update"):
-                self.pos = self.pos + jnp.asarray(
-                    [1 if a else 0 for a in self.active], jnp.int32
-                )
-                self.last_tok = jnp.where(
-                    jnp.asarray(self.active), nxt, self.last_tok
-                )
-            n_stepped = self.n_active
             with TraceAnnotation("engine.step.readback"):
-                for slot in range(self.M):
-                    if not self.active[slot]:
-                        continue
+                nxt = np.array(nxt)  # every slot's token in one copy
+                c.step_host_syncs += 1
+                for slot, t in sampled.items():
+                    nxt[slot] = int(t)
+                c.step_host_syncs += len(sampled)
+                c.sampled_slot_steps += len(sampled)
+            with TraceAnnotation("engine.step.update"):  # host only
+                n_stepped = self.n_active
+                self.pos += self.active
+                self.last_tok = np.where(self.active, nxt, self.last_tok)
+                for slot in np.flatnonzero(self.active).tolist():
                     req = self._slot_req[slot]
                     tok = int(nxt[slot])
-                    c.step_host_syncs += 1
                     res = self.results[req.uid]
                     res.tokens.append(tok)
                     res.decode_s += dt

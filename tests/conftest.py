@@ -62,6 +62,20 @@ def sample_inputs(model, batch=2, seq=12, extra=0, key=0):
     return toks, toks
 
 
+def pad_kv(cache, n):
+    """Grow a prefill cache's self-attention K/V by n empty positions
+    (position -1); cross and state caches keep their size."""
+    cache = dict(cache)
+    for k in ("k", "v"):
+        if k in cache:
+            cache[k] = jnp.pad(
+                cache[k], ((0, 0), (0, 0), (0, n), (0, 0), (0, 0))
+            )
+    if "pos" in cache:
+        cache["pos"] = jnp.pad(cache["pos"], ((0, 0), (0, n)), constant_values=-1)
+    return cache
+
+
 @pytest.fixture(params=ASSIGNED_ARCHS)
 def arch_name(request):
     return request.param

@@ -3,7 +3,7 @@
 The TPU compiler refuses what interpret mode accepts: block shapes off the
 (8, 128) tiling, kernels over the VMEM budget, programs over HBM. These
 tests compile the three Pallas kernels at glm4-9b's published widths, and
-one glm4-9b decode step at the one-chip share `chip_smoke.py` serves (20
+the engine's glm4-9b decode step at the one-chip share `chip_smoke.py` serves (20
 of 40 layers, max_batch 8, max_seq 2048), for one chip of a `v5e:2x2`
 topology. Nothing runs, so they say nothing about results or times.
 
@@ -24,6 +24,7 @@ from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import rmsnorm
 from repro.models import RuntimeFlags, build_model
+from repro.serving.engine import decode_step
 
 GLM4 = get_config("glm4-9b")
 H, K, DH, D = GLM4.n_heads, GLM4.n_kv_heads, GLM4.head_dim, GLM4.d_model
@@ -97,6 +98,7 @@ def test_glm4_decode_step_fits_one_chip(one_chip):
     )
     cache = on_chip(jax.eval_shape(lambda: model.init_cache(8, 2048)[0]))
     tok = _spec((8,), jnp.int32, one_chip)
-    compiled = jax.jit(model.decode).lower(params, cache, tok, tok).compile()
+    # the engine's served program: decode and greedy argmax in one
+    compiled = decode_step.lower(model, params, cache, tok, tok).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes < V5E_HBM_BYTES

@@ -10,22 +10,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import sample_inputs, smoke_model
+from conftest import pad_kv, sample_inputs, smoke_model
 
 TOL = 2e-3
 S, EXTRA, B = 12, 3, 2
-
-
-def pad_kv(cache, n):
-    cache = dict(cache)
-    for k in ("k", "v"):
-        if k in cache:
-            cache[k] = jnp.pad(
-                cache[k], ((0, 0), (0, 0), (0, n), (0, 0), (0, 0))
-            )
-    if "pos" in cache:
-        cache["pos"] = jnp.pad(cache["pos"], ((0, 0), (0, n)), constant_values=-1)
-    return cache
 
 
 def test_prefill_matches_forward(arch_name):

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import pad_kv
 from repro.configs import get_config
 from repro.models import RuntimeFlags, build_model
 from repro.serving import (
@@ -106,11 +107,43 @@ class TestEngine:
         eng.generate(reqs)
         assert eng.counters == EngineCounters(
             steps=4, prefills=3, slot_steps=7,
-            step_host_syncs=4 + 7,  # a block_until_ready and an int() per slot
+            # a block_until_ready and one read of every slot's token per step
+            step_host_syncs=4 * 2, sampled_slot_steps=0,
         )
         assert eng.counters.slot_steps == sum(r.max_new_tokens - 1 for r in reqs)
         eng.reset()
         assert eng.counters == EngineCounters()
+
+    def test_mixed_batch_samples_only_where_asked(self):
+        """A greedy and a sampling request share the batch: each gets its
+        solo tokens, and only the sampling one takes the host path."""
+        from repro.serving.engine import SamplingParams
+
+        m, p = model_params()
+        greedy = mk_req(0, new=5)
+        hot = dataclasses.replace(
+            mk_req(1, new=4),
+            sampling=SamplingParams(temperature=1.0, top_k=20, seed=3))
+        eng = InferenceEngine(m, p, max_batch=2, max_seq=48)
+        out = eng.generate([greedy, hot])
+        for r in (greedy, hot):
+            solo = InferenceEngine(m, p, max_batch=1, max_seq=48).generate([r])
+            assert solo[r.uid].tokens == out[r.uid].tokens, r.uid
+        c = eng.counters
+        assert c.sampled_slot_steps == hot.max_new_tokens - 1
+        # a sampled token is one more read in its step
+        assert c.step_host_syncs == 2 * c.steps + c.sampled_slot_steps
+
+    def test_decode_program_is_named_decode(self):
+        """The benchmark finds the decode program in the device trace as
+        `jit_decode*` (bench/benchlib/tracing.py), so its name must stay."""
+        m, p = model_params()
+        eng = InferenceEngine(m, p, max_batch=2, max_seq=48)
+        fn = eng._decode.func
+        assert fn.__name__.startswith("decode")
+        tok = np.zeros((2,), np.int32)
+        hlo = fn.lower(m, p, eng._cache, tok, tok).as_text()
+        assert "module @jit_decode" in hlo
 
     def test_recurrent_arch_engine(self):
         """Continuous batching over a state-cache arch (zamba2)."""
@@ -309,6 +342,51 @@ class TestEngineAllArchs:
         eng = InferenceEngine(m, p, max_batch=2, max_seq=24, enc_len=10)
         out = eng.generate(reqs)
         assert all(len(r.tokens) == 3 for r in out.values())
+
+
+def _greedy_by_hand(m, p, prompt, new):
+    """Greedy tokens of one request from the model alone: prefill, then
+    `decode` at explicit positions, with an argmax per step."""
+    if isinstance(prompt, dict):
+        batch = {k: v[None] for k, v in prompt.items()}
+        plen = prompt["dec_tokens"].shape[0]
+    else:
+        batch, plen = prompt[None], prompt.shape[0]
+    logits, cache = m.prefill(p, batch)
+    cache = pad_kv(cache, new)
+    toks = [int(jnp.argmax(logits[0]))]
+    for i in range(new - 1):
+        logits, cache = m.decode(p, cache, jnp.asarray([toks[-1]], jnp.int32),
+                                 jnp.asarray([plen + i], jnp.int32))
+        toks.append(int(jnp.argmax(logits[0])))
+    return toks
+
+
+@pytest.mark.parametrize("name", ["llama2-7b", "zamba2-7b",
+                                  "seamless-m4t-large-v2"])
+def test_engine_greedy_equals_an_independent_loop(name):
+    """Three requests over two slots: r0 and r1 step three times
+    together, then r2 takes r0's slot and steps twice beside r1, at other
+    positions. The engine's greedy tokens equal a loop over the model's
+    own prefill and decode."""
+    m, p = model_params(name)
+    enc_len, reqs = 0, []
+    for i, (n, new) in enumerate([(5, 4), (7, 6), (4, 3)]):
+        dec = jax.random.randint(jax.random.PRNGKey(10 + i), (n,), 0,
+                                 m.cfg.vocab_size)
+        prompt = dec
+        if m.is_encdec:
+            enc_len = 8
+            enc = jax.random.normal(jax.random.PRNGKey(20 + i),
+                                    (enc_len, m.cfg.d_model)) * 0.02
+            prompt = {"enc_embeds": enc, "dec_tokens": dec}
+        reqs.append(GenRequest(uid=i, prompt=prompt, max_new_tokens=new))
+    eng = InferenceEngine(m, p, max_batch=2, max_seq=24, enc_len=enc_len)
+    out = eng.generate(reqs)
+    assert (eng.counters.steps, eng.counters.slot_steps) == (5, 10)
+    for r in reqs:
+        want = _greedy_by_hand(m, p, r.prompt, r.max_new_tokens)
+        assert out[r.uid].tokens == want, (name, r.uid)
 
 
 def test_launch_serve_writes_a_chrome_trace(tmp_path):
