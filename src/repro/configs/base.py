@@ -28,6 +28,7 @@ class ModelConfig:
     d_head: int = 0  # 0 -> d_model // n_heads
     # --- attention ---------------------------------------------------------
     rope_theta: float = 1e6
+    partial_rotary_factor: float = 1.0  # share of each head's dims RoPE rotates
     qkv_bias: bool = False
     mrope_sections: Tuple[int, ...] = ()  # qwen2-vl M-RoPE (t, h, w) dims
     window: int = 0  # sliding-window size, 0 = full attention
@@ -52,6 +53,7 @@ class ModelConfig:
     # --- embedding frontend stub (vlm/audio) -----------------------------------
     embeds_input: bool = False  # input_specs feeds (B, S, d_model) embeddings
     # --- misc -------------------------------------------------------------------
+    norm: str = "rms"  # rms | layernorm1p (LayerNorm, gain 1 + gamma, bias)
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
@@ -60,6 +62,11 @@ class ModelConfig:
     @property
     def head_dim(self) -> int:
         return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def rope_dim(self) -> int:
+        """Leading dims of each head that RoPE rotates; the rest pass."""
+        return int(self.head_dim * self.partial_rotary_factor)
 
     @property
     def padded_vocab(self) -> int:

@@ -1,5 +1,9 @@
 """nemotron-4-15b [dense] — 32L d_model=6144 48H (GQA kv=8) d_ff=24576
-vocab=256000 — GQA, squared-ReLU MLP (no gate). [arXiv:2402.16819]
+vocab=256000 untied — GQA, squared-ReLU MLP (no gate), LayerNorm with a
+(1 + gamma) gain and a bias (LayerNorm1P), RoPE on the first half of each
+head. [arXiv:2402.16819 Table 1 and section 2; the block as Hugging Face's
+`nemotron` model type computes it (NemotronLayerNorm1P,
+partial_rotary_factor 0.5)]
 """
 
 from .base import ModelConfig, register
@@ -14,7 +18,9 @@ FULL = ModelConfig(
     d_ff=24576,
     vocab_size=256000,
     rope_theta=1e4,
+    partial_rotary_factor=0.5,
     activation="relu2",  # squared ReLU, 2-matrix MLP
+    norm="layernorm1p",
 )
 
 SMOKE = ModelConfig(
@@ -27,7 +33,9 @@ SMOKE = ModelConfig(
     d_ff=512,
     vocab_size=1024,
     rope_theta=1e4,
+    partial_rotary_factor=0.5,
     activation="relu2",
+    norm="layernorm1p",
     vocab_pad_multiple=64,
 )
 

@@ -209,20 +209,21 @@ def _project_qkv(
         k = k + p["bk"]
         v = v + p["bv"]
     if positions is not None:
-        if cfg.mrope_sections:
-            m = mrope_positions
-            if m is None:
-                m = text_mrope_positions(positions)
-            qr = apply_mrope(q, m, cfg.head_dim, cfg.rope_theta, cfg.mrope_sections)
-            kr = apply_mrope(k, m, cfg.head_dim, cfg.rope_theta, cfg.mrope_sections)
-        else:
-            qr = apply_rope(q, positions, cfg.head_dim, cfg.rope_theta)
-            kr = apply_rope(k, positions, cfg.head_dim, cfg.rope_theta)
-        if rope_flag is None:
-            q, k = qr, kr
-        else:  # traced per-layer iRoPE selection (inside lax.scan)
-            q = jnp.where(rope_flag, qr, q)
-            k = jnp.where(rope_flag, kr, k)
+        with jax.named_scope("rope"):
+            if cfg.mrope_sections:
+                m = mrope_positions
+                if m is None:
+                    m = text_mrope_positions(positions)
+                qr = apply_mrope(q, m, cfg.head_dim, cfg.rope_theta, cfg.mrope_sections)
+                kr = apply_mrope(k, m, cfg.head_dim, cfg.rope_theta, cfg.mrope_sections)
+            else:
+                qr = apply_rope(q, positions, cfg.head_dim, cfg.rope_theta, cfg.rope_dim)
+                kr = apply_rope(k, positions, cfg.head_dim, cfg.rope_theta, cfg.rope_dim)
+            if rope_flag is None:
+                q, k = qr, kr
+            else:  # traced per-layer iRoPE selection (inside lax.scan)
+                q = jnp.where(rope_flag, qr, q)
+                k = jnp.where(rope_flag, kr, k)
     q = constrain(q, ("batch", "seq", "heads", None))
     k = constrain(k, ("batch", "seq", "kv_heads", None))
     v = constrain(v, ("batch", "seq", "kv_heads", None))

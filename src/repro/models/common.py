@@ -26,6 +26,8 @@ __all__ = [
     "axes_of",
     "rms_norm",
     "layer_norm",
+    "init_norm",
+    "norm",
     "dense",
     "activation_fn",
     "RuntimeFlags",
@@ -142,11 +144,35 @@ def rms_norm(x: jax.Array, gamma: jax.Array, eps: float) -> jax.Array:
 
 
 def layer_norm(x: jax.Array, gamma: jax.Array, beta: jax.Array, eps: float) -> jax.Array:
+    """LayerNorm in float32, gain and bias included; back in x's dtype."""
     dt = x.dtype
     xf = x.astype(jnp.float32)
     mu = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.var(xf, axis=-1, keepdims=True)
-    return ((xf - mu) * jax.lax.rsqrt(var + eps)).astype(dt) * gamma + beta
+    y = (xf - mu) * jax.lax.rsqrt(var + eps)
+    return (y * gamma.astype(jnp.float32) + beta.astype(jnp.float32)).astype(dt)
+
+
+def init_norm(init: Initializer, name: str, cfg) -> Dict[str, jax.Array]:
+    """The leaves of one norm site as `cfg.norm` lays them out: a gain
+    `name` (ones for RMSNorm; zeros for LayerNorm1p, whose gain is
+    1 + gamma) and, for LayerNorm1p, a bias `name + "_bias"` beside it."""
+    d = (cfg.d_model,)
+    if cfg.norm == "layernorm1p":
+        return {name: init.param(name, d, ("p_embed",), zeros=True),
+                name + "_bias": init.param(name + "_bias", d, ("p_embed",),
+                                           zeros=True)}
+    if cfg.norm != "rms":
+        raise ValueError(f"unknown norm {cfg.norm!r}")
+    return {name: init.param(name, d, ("p_embed",), ones=True)}
+
+
+def norm(x: jax.Array, p: dict, name: str, cfg) -> jax.Array:
+    """The configured norm of x with the leaves `init_norm` made in p."""
+    if cfg.norm == "layernorm1p":
+        gain = 1.0 + p[name].astype(jnp.float32)
+        return layer_norm(x, gain, p[name + "_bias"], cfg.norm_eps)
+    return rms_norm(x, p[name], cfg.norm_eps)
 
 
 def dense(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None) -> jax.Array:

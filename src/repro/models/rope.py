@@ -1,4 +1,5 @@
-"""Rotary position embeddings: standard RoPE and Qwen2-VL M-RoPE.
+"""Rotary position embeddings: standard RoPE (whole or partial head) and
+Qwen2-VL M-RoPE.
 
 M-RoPE splits the head_dim/2 frequency bands into sections driven by
 (temporal, height, width) position streams; text tokens carry identical
@@ -31,12 +32,19 @@ def _rotate(x: jax.Array, angles: jax.Array) -> jax.Array:
 
 
 def apply_rope(
-    x: jax.Array, positions: jax.Array, head_dim: int, theta: float
+    x: jax.Array, positions: jax.Array, head_dim: int, theta: float,
+    rope_dim: int = 0,
 ) -> jax.Array:
-    """x: (B, S, H, D); positions: (B, S) int32."""
-    inv = rope_freqs(head_dim, theta)  # (D/2,)
-    angles = positions[..., None, None].astype(jnp.float32) * inv  # (B,S,1,D/2)
-    return _rotate(x, angles)
+    """x: (B, S, H, D); positions: (B, S) int32. Rotates the first
+    `rope_dim` dims of each head (0: all of them), rotate-half, with the
+    frequencies taken over those dims; the rest pass through (partial
+    rotary, as Nemotron's partial_rotary_factor)."""
+    r = rope_dim or head_dim
+    inv = rope_freqs(r, theta)  # (r/2,)
+    angles = positions[..., None, None].astype(jnp.float32) * inv  # (B,S,1,r/2)
+    if r == head_dim:
+        return _rotate(x, angles)
+    return jnp.concatenate([_rotate(x[..., :r], angles), x[..., r:]], -1)
 
 
 def apply_mrope(

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from ..sharding import Axes, constrain
 from .attention import attention_forward, decode_attention, init_attention
-from .common import DTYPES, Initializer, RuntimeFlags, init_ctx, rms_norm
+from .common import DTYPES, Initializer, RuntimeFlags, init_ctx, init_norm, norm
 from .mamba2 import (
     init_mamba2,
     init_mamba_state,
@@ -98,11 +98,10 @@ def _iro_flags(cfg: ModelConfig, n: int) -> Optional[jax.Array]:
 
 
 def _init_attn_block(init: Initializer, cfg: ModelConfig) -> dict:
-    sub = {}
-    sub["attn_norm"] = init.param("attn_norm", (cfg.d_model,), ("p_embed",), ones=True)
+    sub = init_norm(init, "attn_norm", cfg)
     a = init.child("attn")
     sub["attn"] = init_attention(a, cfg)
-    sub["mlp_norm"] = init.param("mlp_norm", (cfg.d_model,), ("p_embed",), ones=True)
+    sub.update(init_norm(init, "mlp_norm", cfg))
     if cfg.n_experts:
         m = init.child("moe")
         sub["moe"] = init_moe(m, cfg)
@@ -114,22 +113,22 @@ def _init_attn_block(init: Initializer, cfg: ModelConfig) -> dict:
 
 def _init_mamba_block(init: Initializer, cfg: ModelConfig) -> dict:
     return {
-        "norm": init.param("norm", (cfg.d_model,), ("p_embed",), ones=True),
+        **init_norm(init, "norm", cfg),
         "mamba": init_mamba2(init.child("mamba"), cfg),
     }
 
 
 def _init_mlstm_block(init: Initializer, cfg: ModelConfig) -> dict:
     return {
-        "norm": init.param("norm", (cfg.d_model,), ("p_embed",), ones=True),
+        **init_norm(init, "norm", cfg),
         "mlstm": init_mlstm(init.child("mlstm"), cfg),
     }
 
 
 def _init_slstm_block(init: Initializer, cfg: ModelConfig) -> dict:
     return {
-        "norm": init.param("norm", (cfg.d_model,), ("p_embed",), ones=True),
-        "ffn_norm": init.param("ffn_norm", (cfg.d_model,), ("p_embed",), ones=True),
+        **init_norm(init, "norm", cfg),
+        **init_norm(init, "ffn_norm", cfg),
         "slstm": init_slstm(init.child("slstm"), cfg),
     }
 
@@ -164,9 +163,7 @@ def init_decoder_params(
             "embed", (cfg.padded_vocab, cfg.d_model), ("p_vocab", "p_embed"),
             scale=0.02,
         )
-        params["final_norm"] = top.param(
-            "final_norm", (cfg.d_model,), ("p_embed",), ones=True
-        )
+        params.update(init_norm(top, "final_norm", cfg))
         if not cfg.tie_embeddings:
             params["lm_head"] = top.param(
                 "lm_head", (cfg.d_model, cfg.padded_vocab), ("p_embed", "p_vocab")
@@ -234,11 +231,13 @@ def embed_inputs(params: dict, cfg: ModelConfig, inputs: jax.Array) -> jax.Array
 
 
 def logits_from_hidden(params: dict, cfg: ModelConfig, h: jax.Array) -> jax.Array:
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("final_norm"):
+        h = norm(h, params, "final_norm", cfg)
     w = params.get("lm_head")
     if w is None:  # tied embeddings
         w = params["embed"].T
-    logits = jnp.einsum("...d,dv->...v", h, w)
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("...d,dv->...v", h, w)
     ax = ("batch", "seq", "vocab") if logits.ndim == 3 else ("batch", "vocab")
     return constrain(logits, ax)
 
@@ -259,18 +258,23 @@ def _attn_block_apply(
     block boundaries — unsharded by default, model-axis-sharded under the
     sequence-parallel rule set (TRAIN_RULES_SP)."""
     x = constrain(x, ("batch", "seq_res", "embed"))
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    a, kv = attention_forward(
-        lp["attn"], h, cfg, rt, positions,
-        causal=True, window=window, rope_flag=rope_flag,
-        mrope_positions=mrope_positions,
-    )
+    with jax.named_scope("attn_norm"):
+        h = norm(x, lp, "attn_norm", cfg)
+    with jax.named_scope("attention"):
+        a, kv = attention_forward(
+            lp["attn"], h, cfg, rt, positions,
+            causal=True, window=window, rope_flag=rope_flag,
+            mrope_positions=mrope_positions,
+        )
     x = constrain(x + a, ("batch", "seq_res", "embed"))
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    with jax.named_scope("mlp_norm"):
+        h = norm(x, lp, "mlp_norm", cfg)
     if "moe" in lp:
-        m, aux = moe_forward(lp["moe"], h, cfg, rt.moe_dispatch)
+        with jax.named_scope("moe"):
+            m, aux = moe_forward(lp["moe"], h, cfg, rt.moe_dispatch)
     else:
-        m, aux = mlp_forward(lp["mlp"], h, cfg), {}
+        with jax.named_scope("mlp"):
+            m, aux = mlp_forward(lp["mlp"], h, cfg), {}
     return constrain(x + m, ("batch", "seq_res", "embed")), kv, aux
 
 
@@ -284,18 +288,23 @@ def _attn_block_decode(
     rope_flag,
     window: int,
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array], dict]:
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    a, kv = decode_attention(
-        lp["attn"], h, cfg, rt, pos, cache_k, cache_v, cache_pos,
-        window=window, rope_flag=rope_flag,
-    )
+    with jax.named_scope("attn_norm"):
+        h = norm(x, lp, "attn_norm", cfg)
+    with jax.named_scope("attention"):
+        a, kv = decode_attention(
+            lp["attn"], h, cfg, rt, pos, cache_k, cache_v, cache_pos,
+            window=window, rope_flag=rope_flag,
+        )
     x = x + a
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    with jax.named_scope("mlp_norm"):
+        h = norm(x, lp, "mlp_norm", cfg)
     if "moe" in lp:
-        hm, aux = moe_forward(lp["moe"], h[:, None, :], cfg, rt.moe_dispatch)
+        with jax.named_scope("moe"):
+            hm, aux = moe_forward(lp["moe"], h[:, None, :], cfg, rt.moe_dispatch)
         m = hm[:, 0]
     else:
-        m, aux = mlp_forward(lp["mlp"], h, cfg), {}
+        with jax.named_scope("mlp"):
+            m, aux = mlp_forward(lp["mlp"], h, cfg), {}
     return x + m, kv, aux
 
 
@@ -374,7 +383,7 @@ def _hybrid_stack(params, cfg, rt, x, positions, collect_cache: bool):
 
     def mamba_layer(carry, lp):
         x = carry
-        h = rms_norm(x, lp["norm"], cfg.norm_eps)
+        h = norm(x, lp, "norm", cfg)
         y, st = mamba2_forward(lp["mamba"], h, cfg, chunk=rt.mamba_chunk)
         ys = st if collect_cache else None
         return x + y, ys
@@ -409,7 +418,7 @@ def _hybrid_decode(params, cfg, rt, x, pos, cache):
     def mamba_layer(carry, xs):
         x = carry
         lp, st = xs
-        h = rms_norm(x, lp["norm"], cfg.norm_eps)
+        h = norm(x, lp, "norm", cfg)
         y, st_new = mamba2_decode_step(lp["mamba"], h, st, cfg)
         return x + y, st_new
 
@@ -445,7 +454,7 @@ def _hybrid_decode(params, cfg, rt, x, pos, cache):
 def _ssm_stack(params, cfg, rt, x, collect_cache: bool):
     def mlstm_layer(carry, lp):
         x = carry
-        h = rms_norm(x, lp["norm"], cfg.norm_eps)
+        h = norm(x, lp, "norm", cfg)
         y, st = mlstm_forward(lp["mlstm"], h, cfg, chunk=rt.mlstm_chunk)
         return x + y, st if collect_cache else None
 
@@ -453,7 +462,7 @@ def _ssm_stack(params, cfg, rt, x, collect_cache: bool):
         x = carry
         glp, slp = xs
         x, msts = jax.lax.scan(mlstm_layer, x, glp)
-        h = rms_norm(x, slp["norm"], cfg.norm_eps)
+        h = norm(x, slp, "norm", cfg)
         y, sst = slstm_forward(slp["slstm"], h, cfg)
         # slstm block: cell + its own gated FFN applied inside slstm_forward
         x = x + y
@@ -470,7 +479,7 @@ def _ssm_decode(params, cfg, rt, x, cache):
     def mlstm_layer(carry, xs):
         x = carry
         lp, st = xs
-        h = rms_norm(x, lp["norm"], cfg.norm_eps)
+        h = norm(x, lp, "norm", cfg)
         y, st_new = mlstm_decode_step(lp["mlstm"], h, st, cfg)
         return x + y, st_new
 
@@ -478,7 +487,7 @@ def _ssm_decode(params, cfg, rt, x, cache):
         x = carry
         glp, slp, gmst, gsst = xs
         x, mst = jax.lax.scan(mlstm_layer, x, (glp, gmst))
-        h = rms_norm(x, slp["norm"], cfg.norm_eps)
+        h = norm(x, slp, "norm", cfg)
         y, sst = slstm_decode_step(slp["slstm"], h, gsst, cfg)
         return x + y, (mst, sst)
 
