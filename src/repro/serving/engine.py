@@ -21,7 +21,16 @@ spans (`engine.prefill` with its children `.dispatch`, `.sync`,
 They are written into the profiler's trace, on the device's clock, only
 while a profiler runs, and cost well under a microsecond each otherwise. `InferenceEngine.counters` counts steps,
 prefills, active slots stepped, device-to-host syncs and slot-steps that
-took the host sampling path.
+took the host sampling path, and admissions that did.
+
+An admission is two compiled programs and one host wait: `prefill_step`
+(the model's batch-1 prefill and its greedy first token), then
+`splice_slot`, which writes that request's cache into its slot of the
+batched cache in place (the batched cache is donated). The slot is a
+traced int32, so both compile once per prompt length, not per slot. After
+one wait for both, `submit` reads the first token once. A request that
+samples draws its first token with `sample_token` from the prefill's
+logits instead (`EngineCounters.sampled_prefills`).
 
 Slot splicing is generic across cache families (attention KV, Mamba/xLSTM
 states, enc-dec cross KV): the logical-axes tree from `model.init_cache`
@@ -80,6 +89,45 @@ def decode_step(
     return (logits, jnp.argmax(logits, axis=-1).astype(jnp.int32)), cache
 
 
+@functools.partial(jax.jit, static_argnums=0)
+def prefill_step(model: Model, params: dict, prompt: Any):
+    """One admission's prefill as one program: the model's batch-1 prefill
+    and the greedy first token. -> (logits (1, V), token () int32, cache).
+    It compiles once per prompt length. The benchmark finds the program in
+    the device trace by its name, `jit_prefill_step`."""
+    logits, cache = model.prefill(params, prompt)
+    return logits, jnp.argmax(logits[0]).astype(jnp.int32), cache
+
+
+@functools.partial(jax.jit, static_argnames="batch_axes", donate_argnums=0)
+def splice_slot(cache: dict, cache1: dict, slot: jax.Array,
+                batch_axes: tuple) -> dict:
+    """Write a batch-1 prefill cache into slot `slot` of the batched cache,
+    in place (`cache` is donated). `batch_axes` holds each leaf's batch
+    axis, in the order of `jax.tree.leaves(cache)`. Dims shorter than the
+    batched cache's (the prompt's kv seq) are padded with -1 (int32 leaves:
+    positions, so "empty") or 0."""
+
+    def ins(full, one, bax):
+        one = jnp.squeeze(one, axis=bax)
+        # pad any capacity-sized dims (kv seq) up to the full buffer
+        target = full.shape[:bax] + full.shape[bax + 1 :]
+        pads = []
+        for have, want in zip(one.shape, target):
+            assert have <= want, (one.shape, full.shape)
+            pads.append((0, want - have))
+        if any(p[1] for p in pads):
+            cv = -1 if one.dtype == jnp.int32 else 0
+            one = jnp.pad(one, pads, constant_values=cv)
+        return jax.lax.dynamic_update_index_in_dim(full, one, slot, axis=bax)
+
+    leaves, tree = jax.tree.flatten(cache)
+    return tree.unflatten(
+        ins(full, one, bax) for full, one, bax
+        in zip(leaves, tree.flatten_up_to(cache1), batch_axes)
+    )
+
+
 @dataclasses.dataclass(frozen=True)
 class SamplingParams:
     temperature: float = 0.0  # 0 = greedy
@@ -122,6 +170,8 @@ class EngineCounters:
     step_host_syncs: int = 0
     # slot-steps whose request samples, so whose token the host drew
     sampled_slot_steps: int = 0
+    # admissions whose request samples, so whose first token the host drew
+    sampled_prefills: int = 0
 
 
 class InferenceEngine:
@@ -140,13 +190,18 @@ class InferenceEngine:
         self._enc_len = enc_len
         cache, caxes = model.init_cache(max_batch, max_seq, enc_len=enc_len)
         self._cache = cache
-        self._batch_axis = jax.tree.map(
+        batch_axes = jax.tree.map(
             lambda ax: ax.index("kv_batch") if "kv_batch" in ax else 0, caxes
         )
         self._clear_slots()
         # (params, cache, tokens, pos) -> ((logits, next tokens), cache)
         self._decode = functools.partial(decode_step, model)
-        self._prefill = jax.jit(model.prefill)
+        # (params, prompt) -> (logits, first token, batch-1 cache)
+        self._prefill = functools.partial(prefill_step, model)
+        # (cache, cache1, slot) -> cache, the old cache donated
+        self._splice_slot = functools.partial(
+            splice_slot, batch_axes=tuple(jax.tree.leaves(batch_axes))
+        )
 
     # ------------------------------------------------------------- slots
     def _clear_slots(self) -> None:
@@ -171,8 +226,9 @@ class InferenceEngine:
         self._cache = cache
 
     def warmup(self, sample_prompt: Any) -> None:
-        """Trace+compile prefill/decode/splice for this engine's shapes so
-        the first timed request doesn't pay compilation."""
+        """Trace+compile `prefill_step`, `splice_slot` and `decode_step` for
+        this engine's shapes (and this prompt length) so the first timed
+        request doesn't pay compilation."""
         self.generate([GenRequest(uid=-987654, prompt=sample_prompt,
                                   max_new_tokens=2)])
         self.reset()
@@ -188,27 +244,13 @@ class InferenceEngine:
     def n_active(self) -> int:
         return int(self.active.sum())
 
-    def _splice(self, cache1: dict, slot: int, prompt_len: int) -> None:
-        """Insert a batch-1 prefill cache into slot `slot`."""
-
-        def ins(full, one, bax):
-            one = jnp.squeeze(one, axis=bax)
-            # pad any capacity-sized dims (kv seq) up to the full buffer
-            target = full.shape[:bax] + full.shape[bax + 1 :]
-            pads = []
-            for have, want in zip(one.shape, target):
-                assert have <= want, (one.shape, full.shape)
-                pads.append((0, want - have))
-            if any(p[1] for p in pads):
-                cv = -1 if one.dtype == jnp.int32 else 0
-                one = jnp.pad(one, pads, constant_values=cv)
-            return jax.lax.dynamic_update_index_in_dim(full, one, slot, axis=bax)
-
-        self._cache = jax.tree.map(ins, self._cache, cache1, self._batch_axis)
-
     # ----------------------------------------------------------- serving
     def submit(self, req: GenRequest) -> int:
-        """Prefill + occupy a slot. Returns the slot index."""
+        """Prefill + occupy a slot. Returns the slot index.
+
+        Two compiled programs (`prefill_step`, `splice_slot`), one wait for
+        both, one read of the first token; the slot bookkeeping after it is
+        host work."""
         slots = self.free_slots()
         if not slots:
             raise RuntimeError("no free slot")
@@ -222,11 +264,18 @@ class InferenceEngine:
                 prompt = req.prompt[None]
                 plen = prompt.shape[1]
             with TraceAnnotation("engine.prefill.dispatch"):
-                logits, cache1 = self._prefill(self.params, prompt)
-            with TraceAnnotation("engine.prefill.sync"):
-                tok = int(sample_token(logits[0], req.sampling, req.uid, 0))
+                logits, tok, cache1 = self._prefill(self.params, prompt)
+                if req.sampling.temperature > 0.0:
+                    tok = sample_token(logits[0], req.sampling, req.uid, 0)
+                    self.counters.sampled_prefills += 1
             with TraceAnnotation("engine.splice"):
-                self._splice(cache1, slot, plen)
+                self._cache = self._splice_slot(self._cache, cache1,
+                                                np.int32(slot))
+            # JAX returns before the device finishes: time the admission's
+            # results, then read the first token once
+            with TraceAnnotation("engine.prefill.sync"):
+                jax.block_until_ready((tok, self._cache))
+                tok = int(tok)
             with TraceAnnotation("engine.prefill.update"):  # host only
                 self.pos[slot] = plen
                 self.last_tok[slot] = tok

@@ -3,9 +3,10 @@
 The TPU compiler refuses what interpret mode accepts: block shapes off the
 (8, 128) tiling, kernels over the VMEM budget, programs over HBM. These
 tests compile the three Pallas kernels at glm4-9b's published widths, and
-the engine's glm4-9b decode step at the one-chip share `chip_smoke.py` serves (20
-of 40 layers, max_batch 8, max_seq 2048), for one chip of a `v5e:2x2`
-topology. Nothing runs, so they say nothing about results or times.
+the engine's glm4-9b decode step and admission programs at the one-chip
+share `chip_smoke.py` serves (20 of 40 layers, max_batch 8, max_seq 2048),
+for one chip of a `v5e:2x2` topology. Nothing runs, so they say nothing
+about results or times.
 
 The topology is described only inside the module fixture: only one process
 at a time may load the TPU library, and pytest-xdist workers import every
@@ -24,7 +25,7 @@ from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import rmsnorm
 from repro.models import RuntimeFlags, build_model
-from repro.serving.engine import decode_step
+from repro.serving.engine import decode_step, prefill_step, splice_slot
 
 GLM4 = get_config("glm4-9b")
 H, K, DH, D = GLM4.n_heads, GLM4.n_kv_heads, GLM4.head_dim, GLM4.d_model
@@ -86,7 +87,9 @@ def test_rmsnorm_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_glm4_decode_step_fits_one_chip(one_chip):
+def _glm4_on_chip(one_chip):
+    """glm4-9b-l20's model, and its parameters and max_batch 8 x max_seq
+    2048 cache as shapes on the described chip."""
     cfg = dataclasses.replace(GLM4, n_layers=20)
     model = build_model(cfg, RuntimeFlags(remat=False))
 
@@ -97,8 +100,31 @@ def test_glm4_decode_step_fits_one_chip(one_chip):
         jax.eval_shape(lambda k: model.init(k)[0], jax.random.PRNGKey(0))
     )
     cache = on_chip(jax.eval_shape(lambda: model.init_cache(8, 2048)[0]))
+    return model, params, cache, on_chip
+
+
+def test_glm4_decode_step_fits_one_chip(one_chip):
+    model, params, cache, _ = _glm4_on_chip(one_chip)
     tok = _spec((8,), jnp.int32, one_chip)
     # the engine's served program: decode and greedy argmax in one
     compiled = decode_step.lower(model, params, cache, tok, tok).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_glm4_admission_splices_in_place(one_chip):
+    """The engine's admission programs for a 15-token prompt: the prefill
+    with its greedy token, then the splice, whose output is the donated
+    cache's own buffers."""
+    model, params, cache, on_chip = _glm4_on_chip(one_chip)
+    prompt = _spec((1, 15), jnp.int32, one_chip)
+    prefill_step.lower(model, params, prompt).compile()
+    _, _, cache1 = jax.eval_shape(
+        lambda p, x: prefill_step(model, p, x), params, prompt)
+    _, caxes = model.init_cache(1, 1)
+    axes = tuple(ax.index("kv_batch") for ax in jax.tree.leaves(caxes))
+    compiled = splice_slot.lower(
+        cache, on_chip(cache1), _spec((), jnp.int32, one_chip),
+        batch_axes=axes).compile()
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes

@@ -20,6 +20,7 @@ from repro.serving import (
     InferenceEngine,
     measure_service_time,
 )
+from repro.serving.engine import prefill_step, splice_slot
 from repro.telemetry import EventRecorder
 
 _CACHE = {}
@@ -108,7 +109,7 @@ class TestEngine:
         assert eng.counters == EngineCounters(
             steps=4, prefills=3, slot_steps=7,
             # a block_until_ready and one read of every slot's token per step
-            step_host_syncs=4 * 2, sampled_slot_steps=0,
+            step_host_syncs=4 * 2, sampled_slot_steps=0, sampled_prefills=0,
         )
         assert eng.counters.slot_steps == sum(r.max_new_tokens - 1 for r in reqs)
         eng.reset()
@@ -131,6 +132,8 @@ class TestEngine:
             assert solo[r.uid].tokens == out[r.uid].tokens, r.uid
         c = eng.counters
         assert c.sampled_slot_steps == hot.max_new_tokens - 1
+        # of the two admissions, only the sampling one drew on the host
+        assert (c.prefills, c.sampled_prefills) == (2, 1)
         # a sampled token is one more read in its step
         assert c.step_host_syncs == 2 * c.steps + c.sampled_slot_steps
 
@@ -144,6 +147,33 @@ class TestEngine:
         tok = np.zeros((2,), np.int32)
         hlo = fn.lower(m, p, eng._cache, tok, tok).as_text()
         assert "module @jit_decode" in hlo
+
+    def test_prefill_program_is_named_prefill(self):
+        """The benchmark finds the prefill program in the device trace as
+        `jit_prefill*`; the splice's program must match neither that nor
+        `jit_decode*`, so that no roofline counts it."""
+        m, p = model_params()
+        eng = InferenceEngine(m, p, max_batch=2, max_seq=48)
+        prompt = mk_req(0).prompt[None]
+        hlo = prefill_step.lower(m, p, prompt).as_text()
+        assert "module @jit_prefill" in hlo
+        _, cache1 = jax.eval_shape(m.prefill, p, prompt)
+        splice = eng._splice_slot
+        hlo = splice.func.lower(eng._cache, cache1, np.int32(0),
+                                **splice.keywords).as_text()
+        assert "module @jit_splice_slot" in hlo
+
+    def test_admission_compiles_once_per_prompt_length(self):
+        """After a warm-up, same-length prompts into every slot compile
+        neither the prefill nor the splice again: the slot is traced."""
+        m, p = model_params()
+        eng = InferenceEngine(m, p, max_batch=3, max_seq=48)
+        eng.warmup(mk_req(0).prompt)
+        sizes = (prefill_step._cache_size(), splice_slot._cache_size())
+        for i in range(eng.M):
+            assert eng.submit(mk_req(10 + i, new=4)) == i
+        assert (prefill_step._cache_size(), splice_slot._cache_size()) \
+            == sizes
 
     def test_recurrent_arch_engine(self):
         """Continuous batching over a state-cache arch (zamba2)."""
@@ -387,6 +417,62 @@ def test_engine_greedy_equals_an_independent_loop(name):
     for r in reqs:
         want = _greedy_by_hand(m, p, r.prompt, r.max_new_tokens)
         assert out[r.uid].tokens == want, (name, r.uid)
+
+
+def _np_splice(full, one, slot, bax):
+    """numpy: `one` (batch 1 along `bax`) into `full` at `slot`, each
+    shorter dim padded with -1 (int32) or 0."""
+    one = np.squeeze(one, axis=bax)
+    target = full.shape[:bax] + full.shape[bax + 1:]
+    one = np.pad(one, [(0, w - h) for h, w in zip(one.shape, target)],
+                 constant_values=-1 if one.dtype == np.int32 else 0)
+    out = full.copy()
+    idx = [slice(None)] * full.ndim
+    idx[bax] = slot
+    out[tuple(idx)] = one
+    return out
+
+
+@pytest.mark.parametrize("name", ["llama2-7b", "zamba2-7b", "xlstm-1.3b",
+                                  "seamless-m4t-large-v2"])
+def test_submit_splices_the_prefill_into_its_slot(name):
+    """Three requests fill three slots, the middle one finishes, and a
+    shorter prompt takes its slot. The engine's cache then equals a numpy
+    splice of the model's own prefill cache into that slot, and the other
+    slots are bit-identical; the first token is the prefill's argmax."""
+    m, p = model_params(name)
+    enc_len = 8 if m.is_encdec else 0
+
+    def prompt(i, n):
+        dec = jax.random.randint(jax.random.PRNGKey(30 + i), (n,), 0,
+                                 m.cfg.vocab_size)
+        if not m.is_encdec:
+            return dec
+        enc = jax.random.normal(jax.random.PRNGKey(40 + i),
+                                (enc_len, m.cfg.d_model)) * 0.02
+        return {"enc_embeds": enc, "dec_tokens": dec}
+
+    eng = InferenceEngine(m, p, max_batch=3, max_seq=24, enc_len=enc_len)
+    for i, n in enumerate([5, 9, 6]):
+        eng.submit(GenRequest(uid=i, prompt=prompt(i, n), max_new_tokens=4))
+    eng._finish(1)
+    before = jax.tree.map(np.array, eng._cache)
+    new = prompt(3, 4)
+    assert eng.submit(GenRequest(uid=3, prompt=new, max_new_tokens=4)) == 1
+
+    batch = jax.tree.map(lambda v: v[None], new)
+    logits, cache1 = jax.jit(m.prefill)(p, batch)
+    assert eng.results[3].tokens == [int(np.argmax(np.asarray(logits[0])))]
+    _, caxes = m.init_cache(3, 24, enc_len=enc_len)
+    want = jax.tree.map(
+        lambda full, one, ax: _np_splice(full, np.asarray(one), 1,
+                                         ax.index("kv_batch")),
+        before, cache1, caxes)
+    got = jax.tree.map(np.asarray, eng._cache)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(g, w)
 
 
 def test_launch_serve_writes_a_chrome_trace(tmp_path):
