@@ -1,7 +1,7 @@
 """Kernel micro-benchmarks.
 
-On this CPU container the Pallas kernels execute via their jnp fallback
-(identical math); wall-times below benchmark THAT path, while the
+The wall-times below are those of the kernels' jnp oracles
+(`repro.kernels.ref`, identical math) on the host backend, while the
 analytic columns report the TPU-target tile economics (VMEM working set,
 arithmetic intensity, roofline-expected time on v5e) derived from the
 BlockSpec shapes — the numbers a TPU run would be judged against.

@@ -50,6 +50,7 @@ from repro.kernels.flash_attention import flash_attention  # noqa: E402
 from repro.kernels.rmsnorm import rmsnorm  # noqa: E402
 from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.launch.serve import serve  # noqa: E402
+from repro.models.attention import core_for_keys  # noqa: E402
 
 N_LAYERS = 20  # of glm4-9b's 40: one stage of a two-chip pipeline
 MAX_BATCH, MAX_SEQ = 8, 2048
@@ -185,7 +186,7 @@ def serve_phase(cfg, *, max_batch: int = MAX_BATCH,
         "serve_s": run.serve_s,
         "tokens": sum(r.n_tokens for r in run.results.values()),
         "attention": (
-            f"prefill {rep.model.rt.attn_impl_for(N_INPUT)} jnp "
+            f"prefill {core_for_keys(N_INPUT)} jnp "
             "(models/attention.attention_core), decode jnp cache attention "
             "(models/attention.decode_attention); no Pallas kernel"
         ),

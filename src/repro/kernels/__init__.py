@@ -1,14 +1,15 @@
-"""Pallas TPU kernels for the serving hot path.
+"""Pallas TPU kernels. None is on the served path: the model runs the jnp
+attention and norms of repro.models, and the kernels are compiled and
+checked on their own (tests/test_kernels.py, chip_smoke.py).
 
-Each kernel ships three layers:
+Each kernel ships two layers:
   <name>.py — pl.pallas_call + BlockSpec VMEM tiling (TPU target)
   ref.py    — pure-jnp oracle (allclose ground truth)
-  ops.py    — jitted dispatch (TPU: kernel; CPU: oracle)
 """
 
-from . import ops, ref
+from . import ref
 from .decode_attention import decode_attention
 from .flash_attention import flash_attention
 from .rmsnorm import rmsnorm
 
-__all__ = ["ops", "ref", "flash_attention", "decode_attention", "rmsnorm"]
+__all__ = ["ref", "flash_attention", "decode_attention", "rmsnorm"]

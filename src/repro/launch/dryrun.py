@@ -53,7 +53,7 @@ ASSIGNED = [
 
 
 def run_case(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
-             rules_override=None, tag: str = "", rt_kwargs=None,
+             rules_override=None, tag: str = "",
              microbatches: int = 1) -> dict:
     mesh_name = "multi" if multi_pod else "single"
     label = f"{arch}__{shape_name}__{mesh_name}" + (f"__{tag}" if tag else "")
@@ -69,7 +69,7 @@ def run_case(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
 
     try:
         case = build_case(arch, shape_name, rules_override=rules_override,
-                          rt_kwargs=rt_kwargs, microbatches=microbatches)
+                          microbatches=microbatches)
         mesh = make_production_mesh(multi_pod=multi_pod)
         chips = mesh.size
         with sh.use_mesh(mesh, case.rules):
@@ -158,13 +158,10 @@ def main() -> None:
     ap.add_argument("--out", default="benchmarks/results/dryrun")
     ap.add_argument("--skip-existing", action="store_true")
     ap.add_argument("--tag", default="", help="variant tag for the JSON name")
-    ap.add_argument("--moe-dispatch", default=None, choices=["einsum", "scatter"])
     ap.add_argument("--rules", default=None,
-                    choices=["train_sp", "decode_v2", "train_attnsp", "train_cp_sp", "decode_v3", "train_fsdp", "train_ep_cp", "train_ep_cp_sp", "decode_v3_ep"],
+                    choices=["train_sp", "decode_v2", "decode_v3", "train_fsdp", "train_ep_cp", "train_ep_cp_sp", "decode_v3_ep"],
                     help="hillclimb rule-set override")
     ap.add_argument("--microbatches", type=int, default=1)
-    ap.add_argument("--attn-seq-shard", action="store_true")
-    ap.add_argument("--attention-impl", default=None)
     args = ap.parse_args()
 
     from .. import sharding as shmod
@@ -173,22 +170,12 @@ def main() -> None:
         None: None,
         "train_sp": shmod.TRAIN_RULES_SP,
         "decode_v2": shmod.DECODE_RULES_V2,
-        "train_attnsp": shmod.TRAIN_RULES_ATTNSP,
-        "train_cp_sp": shmod.TRAIN_RULES_CP_SP,
         "decode_v3": shmod.DECODE_RULES_V3,
         "train_fsdp": shmod.TRAIN_RULES_FSDP,
         "train_ep_cp": shmod.TRAIN_RULES_EP_CP,
         "train_ep_cp_sp": shmod.TRAIN_RULES_EP_CP_SP,
         "decode_v3_ep": shmod.DECODE_RULES_V3_EP,
     }[args.rules]
-    rt_kwargs = {}
-    if args.moe_dispatch:
-        rt_kwargs["moe_dispatch"] = args.moe_dispatch
-    if args.attn_seq_shard:
-        rt_kwargs["attn_seq_shard"] = True
-    if args.attention_impl:
-        rt_kwargs["attention_impl"] = args.attention_impl
-
     archs = ASSIGNED if args.all or not args.arch else [args.arch]
     shapes = list(SHAPES) if args.all or not args.shape else [args.shape]
     meshes = {"single": [False], "multi": [True], "both": [False, True]}[args.mesh]
@@ -208,7 +195,6 @@ def main() -> None:
                 rec = run_case(
                     arch, shape, mp, args.out,
                     rules_override=rules_override, tag=args.tag,
-                    rt_kwargs=rt_kwargs or None,
                     microbatches=args.microbatches,
                 )
                 st = rec["status"]

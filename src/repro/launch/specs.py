@@ -7,11 +7,11 @@ The four assigned shapes:
     decode_32k   seq  32,768  global_batch 128  (decode, KV cache = seq)
     long_500k    seq 524,288  global_batch   1  (long-context decode)
 
-`build_case(arch, shape)` resolves applicability (DESIGN.md §4.3), the
-runtime flags (chunked attention for 32k+; sliding-window serving variant
-for full-attention archs at 500k), and returns everything the dry-run and
-the drivers need: the step callable, abstract args, and logical-axes trees
-for sharding. Nothing here allocates device memory.
+`build_case(arch, shape)` resolves applicability (`skip_reason`), the
+sliding-window serving variant of full-attention archs at 500k, and
+activation checkpointing for training, and returns everything the dry-run
+and the launch scripts need: the step callable, abstract args, and
+logical-axes trees for sharding. Nothing here allocates device memory.
 """
 
 from __future__ import annotations
@@ -66,12 +66,13 @@ SHAPES: Dict[str, ShapeSpec] = {
 
 
 def skip_reason(cfg: ModelConfig, shape: ShapeSpec) -> Optional[str]:
-    """DESIGN.md §4.3: the single documented skip."""
+    """The one (arch x shape) case that is not built, and why."""
     if shape.name == "long_500k" and cfg.n_encoder_layers:
         return (
             "long_500k x enc-dec (seamless): 500k source frames through a "
             "full-attention encoder has no sub-quadratic variant in this "
-            "family; documented skip (DESIGN.md §4.3)."
+            "family, and a sliding window would change what the encoder "
+            "computes."
         )
     return None
 
@@ -80,25 +81,18 @@ def applicable(cfg: ModelConfig, shape: ShapeSpec) -> bool:
     return skip_reason(cfg, shape) is None
 
 
-def _runtime_for(cfg: ModelConfig, shape: ShapeSpec) -> RuntimeFlags:
-    window_override = 0
-    if shape.name == "long_500k" and cfg.family in ("dense", "vlm", "moe"):
+def _config_for(cfg: ModelConfig, shape: ShapeSpec) -> ModelConfig:
+    if (shape.name == "long_500k" and cfg.family in ("dense", "vlm", "moe")
+            and not cfg.window):
         # full-attention families run the sliding-window serving variant;
         # mixtral's native SWA (4096) already bounds the cache.
-        if not cfg.window:
-            window_override = LONG_WINDOW
-    impl = "chunked" if shape.seq > 8192 else "auto"
-    return RuntimeFlags(
-        attention_impl=impl,
-        window_override=window_override,
-        remat=(shape.kind == "train"),
-    )
+        return dataclasses.replace(cfg, window=LONG_WINDOW)
+    return cfg
 
 
-def _cache_len(cfg: ModelConfig, shape: ShapeSpec, rt: RuntimeFlags) -> int:
-    win = rt.window_override or cfg.window
-    if win:
-        return min(shape.seq, win)
+def _cache_len(cfg: ModelConfig, shape: ShapeSpec) -> int:
+    if cfg.window:
+        return min(shape.seq, cfg.window)
     return shape.seq
 
 
@@ -174,9 +168,7 @@ def build_case(
     arch: str,
     shape_name: str,
     opt_cfg: Optional[AdamWConfig] = None,
-    rt_override: Optional[RuntimeFlags] = None,
     rules_override: Optional[AxisRules] = None,
-    rt_kwargs: Optional[dict] = None,
     microbatches: int = 1,
 ) -> Case:
     cfg = get_config(arch)
@@ -184,10 +176,8 @@ def build_case(
     reason = skip_reason(cfg, shape)
     if reason:
         raise ValueError(f"skipped: {reason}")
-    rt = rt_override or _runtime_for(cfg, shape)
-    if rt_kwargs:
-        rt = dataclasses.replace(rt, **rt_kwargs)
-    model = build_model(cfg, rt)
+    cfg = _config_for(cfg, shape)
+    model = build_model(cfg, RuntimeFlags(remat=shape.kind == "train"))
     pshapes, paxes = _abstract_init(model)
 
     if shape.kind == "train":
@@ -217,7 +207,7 @@ def build_case(
 
     # decode
     B = shape.batch
-    clen = _cache_len(cfg, shape, rt)
+    clen = _cache_len(cfg, shape)
     enc_len = shape.seq if cfg.n_encoder_layers else 0
     cshapes, caxes = _abstract_cache(model, B, clen, enc_len)
     tok = jax.ShapeDtypeStruct((B,), jnp.int32)

@@ -1,14 +1,13 @@
 """Grouped-query attention: train/prefill (naive or chunked-flash) + decode.
 
-Three interchangeable implementations of the score->softmax->mix core:
+Two implementations of the score->softmax->mix core, chosen by the number
+of keys alone (`core_for_keys`):
 
-  * naive    — materializes (B, K, G, Sq, Sk) scores; smoke-test scale.
+  * naive    — materializes (B, K, G, Sq, Sk) scores; up to NAIVE_MAX_KEYS.
   * chunked  — double-chunked online-softmax (flash attention in pure jnp):
                outer lax.map over query chunks, inner lax.scan over KV
-               chunks carrying (m, l, acc). Peak memory O(qc * kvc), used
-               for the 32k/500k dry-run shapes on any backend.
-  * pallas   — TPU kernel (repro/kernels/flash_attention.py); selected via
-               RuntimeFlags, falls back to chunked off-TPU.
+               chunks carrying (m, l, acc). Peak memory O(CHUNK * CHUNK);
+               above NAIVE_MAX_KEYS (the 32k/500k dry-run shapes).
 
 Masking supports causal, sliding-window (Mixtral/long_500k serving variant)
 and full (encoder / cross attention). GQA is native: q is shaped
@@ -25,7 +24,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from ..sharding import constrain
-from .common import Initializer, RuntimeFlags
+from .common import Initializer
 from .rope import apply_mrope, apply_rope, text_mrope_positions
 
 __all__ = [
@@ -33,9 +32,14 @@ __all__ = [
     "attention_forward",
     "decode_attention",
     "attention_core",
+    "core_for_keys",
 ]
 
 NEG_INF = -1e30
+# naive attention up to this many keys, chunked attention above
+NAIVE_MAX_KEYS = 2048
+# query and key chunk of chunked attention
+CHUNK = 1024
 
 
 def init_attention(init: Initializer, cfg: ModelConfig) -> dict:
@@ -105,14 +109,14 @@ def chunked_attention(
     k_pos: jax.Array,  # (B, Sk)
     causal: bool,
     window: int,
-    q_chunk: int,
-    kv_chunk: int,
+    q_block: int = CHUNK,
+    kv_block: int = CHUNK,
 ) -> jax.Array:
     """Flash-style double-chunked attention with online softmax."""
     B, Sq, K, G, dh = q.shape
     Sk = k.shape[1]
-    qc = min(q_chunk, Sq)
-    kc = min(kv_chunk, Sk)
+    qc = min(q_block, Sq)
+    kc = min(kv_block, Sk)
     scale = 1.0 / math.sqrt(dh)
 
     # Pad to chunk multiples; padded KV slots get k_pos = -1 (masked).
@@ -171,20 +175,14 @@ def chunked_attention(
     return out[:, :Sq].astype(q.dtype)
 
 
-def attention_core(
-    q, k, v, q_pos, k_pos, causal, window, rt: RuntimeFlags
-) -> jax.Array:
-    impl = rt.attn_impl_for(int(k.shape[1]))
-    if impl == "pallas":
-        from ..kernels import ops as kernel_ops
+def core_for_keys(n_keys: int) -> str:
+    """The attention core `attention_core` runs over n_keys keys."""
+    return "naive" if n_keys <= NAIVE_MAX_KEYS else "chunked"
 
-        return kernel_ops.flash_attention(
-            q, k, v, q_pos, k_pos, causal=causal, window=window
-        )
-    if impl == "chunked":
-        return chunked_attention(
-            q, k, v, q_pos, k_pos, causal, window, rt.q_chunk, rt.kv_chunk
-        )
+
+def attention_core(q, k, v, q_pos, k_pos, causal, window) -> jax.Array:
+    if core_for_keys(int(k.shape[1])) == "chunked":
+        return chunked_attention(q, k, v, q_pos, k_pos, causal, window)
     return naive_attention(q, k, v, q_pos, k_pos, causal, window)
 
 
@@ -249,7 +247,6 @@ def attention_forward(
     p: dict,
     x: jax.Array,  # (B, S, d)
     cfg: ModelConfig,
-    rt: RuntimeFlags,
     positions: jax.Array,  # (B, S)
     *,
     causal: bool = True,
@@ -277,11 +274,7 @@ def attention_forward(
     else:
         k_pos = positions
     qg = q.reshape(B, S, K, G, cfg.head_dim)
-    out = attention_core(qg, k, v, positions, k_pos, causal, window, rt)
-    if rt.attn_seq_shard:
-        # context parallelism: pin the attention output's query-seq dim;
-        # GSPMD shards the whole score/softmax/mix chain spatially.
-        out = constrain(out, ("batch", "attn_q_seq", None, None, None))
+    out = attention_core(qg, k, v, positions, k_pos, causal, window)
     out = out.reshape(B, S, cfg.n_heads, cfg.head_dim)
     y = jnp.einsum("bsnh,nhd->bsd", out, p["wo"])
     return constrain(y, ("batch", "seq_res", "embed")), (k, v)
@@ -291,7 +284,6 @@ def decode_attention(
     p: dict,
     x: jax.Array,  # (B, d) — one new token per sequence
     cfg: ModelConfig,
-    rt: RuntimeFlags,
     pos: jax.Array,  # (B,) current position index
     cache_k: jax.Array,  # (B, Sc, K, dh)
     cache_v: jax.Array,

@@ -38,28 +38,10 @@ DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32, "float16": jnp.float
 
 @dataclasses.dataclass(frozen=True)
 class RuntimeFlags:
-    """Per-invocation execution knobs (orthogonal to the architecture)."""
+    """How the model runs apart from what it computes: whether each layer
+    is wrapped in `jax.checkpoint` (activation checkpointing, for training)."""
 
-    attention_impl: str = "auto"  # auto | naive | chunked | pallas
-    q_chunk: int = 1024
-    kv_chunk: int = 1024
-    mamba_chunk: int = 256
-    mlstm_chunk: int = 256
-    window_override: int = 0  # force sliding-window serving (long_500k dense)
-    remat: bool = True  # activation checkpointing around each layer (train)
-    naive_below: int = 2048  # "auto" uses naive attention below this seq len
-    moe_dispatch: str = "scatter"  # scatter | einsum (Mesh-TF baseline)
-    # Shard the attention core by QUERY SEQUENCE over the model axis
-    # (context parallelism). The escape hatch for archs whose head count
-    # does not divide the model axis (llama4: 40 heads on a 16-wide axis
-    # -> heads fall back to replication and attention runs 16x redundant).
-    # Pairs with the "attn_q_seq" rule (ATTN_SEQ rule sets).
-    attn_seq_shard: bool = False
-
-    def attn_impl_for(self, seq: int) -> str:
-        if self.attention_impl != "auto":
-            return self.attention_impl
-        return "naive" if seq <= self.naive_below else "chunked"
+    remat: bool = True
 
 
 # --------------------------------------------------------------------------

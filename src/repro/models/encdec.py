@@ -101,7 +101,7 @@ def encode(
 
     def body(x, lp):
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        a, _ = attention_forward(lp["attn"], h, cfg, rt, positions, causal=False)
+        a, _ = attention_forward(lp["attn"], h, cfg, positions, causal=False)
         x = x + a
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         return x + mlp_forward(lp["mlp"], h, cfg), None
@@ -118,12 +118,12 @@ def _dec_stack(
 
     def body(x, lp):
         h = rms_norm(x, lp["self_norm"], cfg.norm_eps)
-        a, kv = attention_forward(lp["self_attn"], h, cfg, rt, positions, causal=True)
+        a, kv = attention_forward(lp["self_attn"], h, cfg, positions, causal=True)
         x = x + a
         h = rms_norm(x, lp["cross_norm"], cfg.norm_eps)
         ckv = project_kv(lp["cross_attn"], enc_out, cfg)
         c, _ = attention_forward(
-            lp["cross_attn"], h, cfg, rt, positions,
+            lp["cross_attn"], h, cfg, positions,
             cross_kv=ckv, cross_pos=enc_pos,
         )
         x = x + c
@@ -206,7 +206,6 @@ def encdec_prefill(
 def encdec_decode(
     params: dict,
     cfg: ModelConfig,
-    rt: RuntimeFlags,
     cache: dict,
     token: jax.Array,  # (B,)
     pos: jax.Array,  # (B,)
@@ -221,12 +220,12 @@ def encdec_decode(
         lp, ck, cv, crk, crv = xs
         h = rms_norm(x, lp["self_norm"], cfg.norm_eps)
         a, (kn, vn) = decode_attention(
-            lp["self_attn"], h, cfg, rt, pos, ck, cv, cache["pos"]
+            lp["self_attn"], h, cfg, pos, ck, cv, cache["pos"]
         )
         x = x + a
         h = rms_norm(x, lp["cross_norm"], cfg.norm_eps)
         c, _ = decode_attention(
-            lp["cross_attn"], h, cfg, rt, pos, crk, crv, cache["cross_pos"],
+            lp["cross_attn"], h, cfg, pos, crk, crv, cache["cross_pos"],
             cross=True,
         )
         x = x + c

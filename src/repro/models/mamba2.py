@@ -19,7 +19,7 @@ HLO contains one scan of length S/Q regardless of model depth.
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +27,9 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from ..sharding import constrain
 from .common import Initializer, rms_norm
+
+# steps per chunk of the chunked forward
+CHUNK = 256
 
 __all__ = ["init_mamba2", "mamba2_forward", "mamba2_decode_step", "init_mamba_state"]
 
@@ -90,14 +93,14 @@ def mamba2_forward(
     p: dict,
     x: jax.Array,  # (B, S, d)
     cfg: ModelConfig,
-    chunk: int = 128,
+    chunk: Optional[int] = None,  # None: CHUNK
     state: dict = None,  # continue from a previous state (or None = fresh)
 ) -> Tuple[jax.Array, dict]:
     """Full-sequence chunked forward. Returns (y (B,S,d), final state)."""
     B, S, _ = x.shape
     nh, P, N = cfg.n_ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
     cw = cfg.ssm_conv
-    Q = min(chunk, S)
+    Q = min(chunk or CHUNK, S)
     pad = (-S) % Q
 
     xi_raw, z, B_raw, C_raw, dt = _gates(p, x)

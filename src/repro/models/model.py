@@ -112,8 +112,8 @@ class Model:
     ) -> Tuple[jax.Array, dict]:
         """One token for every sequence in the batch -> (logits, cache)."""
         if self.is_encdec:
-            return encdec.encdec_decode(params, self.cfg, self.rt, cache, token, pos)
-        return transformer.decoder_decode(params, self.cfg, self.rt, cache, token, pos)
+            return encdec.encdec_decode(params, self.cfg, cache, token, pos)
+        return transformer.decoder_decode(params, self.cfg, cache, token, pos)
 
 
 def build_model(cfg: ModelConfig, rt: Optional[RuntimeFlags] = None) -> Model:

@@ -5,8 +5,8 @@ Mixtral-8x22B: 8 experts top-2; Llama-4-Scout: 16 experts top-1.
 Dispatch: per batch-row groups. Tokens pick top-k experts; position within
 each expert's buffer comes from a cumulative sum over the (token, k) slots;
 tokens beyond the expert capacity C = ceil(S*k/E * capacity_factor) are
-dropped (residual passthrough). The combine tensor (B, S, E, C) carries the
-router weights; dispatch is its boolean support.
+dropped (residual passthrough). Tokens reach the (B, E, C, d) expert
+buffers by scatter and come back by gather.
 
 Expert weights are tensor-parallel on the ffn axis inside every expert
 (uniform, always divides); the experts axis itself is a hillclimb knob
@@ -66,17 +66,13 @@ def _route(p: dict, x: jax.Array, cfg: ModelConfig, C: int):
     return gate_w, gate_idx, pos_sel, keep_k, sel, keep, probs, logits
 
 
-def moe_forward(
-    p: dict, x: jax.Array, cfg: ModelConfig, dispatch: str = "scatter"
-) -> Tuple[jax.Array, dict]:
+def moe_forward(p: dict, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, dict]:
     """x: (B, S, d) -> (out (B, S, d), aux losses dict).
 
-    dispatch="scatter" (default): tokens move into (B, E, C, d) expert
-    buffers via scatter and back via gather — O(T*d) data movement, no
-    FLOPs beyond the expert matmuls. dispatch="einsum" is the classic
-    Mesh-TF one-hot form, kept as the §Perf baseline: its dispatch/combine
-    einsums cost O(T*E*C*d) FLOPs, which at 4k+ sequence lengths dwarfs
-    the expert compute itself (this is the llama4-scout hillclimb story).
+    Tokens move into (B, E, C, d) expert buffers via scatter and back via
+    gather: O(T*d) data movement, no FLOPs beyond the expert matmuls. (The
+    Mesh-TF one-hot einsum form, which tests compare against, spends
+    O(T*E*C*d) FLOPs on dispatch and combine.)
     """
     B, S, d = x.shape
     E, k = cfg.n_experts, cfg.top_k
@@ -95,42 +91,27 @@ def moe_forward(
         h = constrain(h, ("batch", "experts", None, "ffn"))
         return jnp.einsum("becf,efd->becd", h, p["w2"])
 
-    if dispatch == "scatter":
-        e_idx = jnp.where(keep_k, gate_idx, E)  # OOB rows dropped by scatter
-        c_idx = jnp.where(keep_k, pos_sel, 0)
-        xk = jnp.broadcast_to(x[:, :, None, :], (B, S, k, d))
+    e_idx = jnp.where(keep_k, gate_idx, E)  # OOB rows dropped by scatter
+    c_idx = jnp.where(keep_k, pos_sel, 0)
+    xk = jnp.broadcast_to(x[:, :, None, :], (B, S, k, d))
 
-        # vmap over the batch row makes it an explicit scatter/gather
-        # batching dim, so GSPMD keeps the data movement local to the
-        # (data-sharded) batch instead of all-reducing buffers.
-        def scatter_row(er, cr, xr):
-            return jnp.zeros((E + 1, C, d), x.dtype).at[er, cr].set(
-                xr, mode="drop"
-            )
+    # vmap over the batch row makes it an explicit scatter/gather
+    # batching dim, so GSPMD keeps the data movement local to the
+    # (data-sharded) batch instead of all-reducing buffers.
+    def scatter_row(er, cr, xr):
+        return jnp.zeros((E + 1, C, d), x.dtype).at[er, cr].set(xr, mode="drop")
 
-        xe = jax.vmap(scatter_row)(e_idx, c_idx, xk)[:, :E]
-        xe = constrain(xe, ("batch", "experts", None, "embed"))
-        ye = experts(xe)
+    xe = jax.vmap(scatter_row)(e_idx, c_idx, xk)[:, :E]
+    xe = constrain(xe, ("batch", "experts", None, "embed"))
+    ye = experts(xe)
 
-        def gather_row(yr, er, cr):
-            return yr[jnp.minimum(er, E - 1), cr]
+    def gather_row(yr, er, cr):
+        return yr[jnp.minimum(er, E - 1), cr]
 
-        yk = jax.vmap(gather_row)(ye, e_idx, c_idx)  # (B, S, k, d)
-        out = jnp.einsum(
-            "bskd,bsk->bsd", yk, gate_w.astype(x.dtype) * keep_k.astype(x.dtype)
-        )
-    elif dispatch == "einsum":
-        e_oh = (sel * keep).astype(x.dtype) * gate_w[..., None].astype(x.dtype)
-        c_oh = jax.nn.one_hot(jnp.where(keep_k, pos_sel, C), C, dtype=x.dtype)
-        combine = jnp.einsum("bske,bskc->bsec", e_oh, c_oh)
-        combine = constrain(combine, ("batch", "seq", "experts", None))
-        disp = (combine > 0).astype(x.dtype)
-        xe = jnp.einsum("bsec,bsd->becd", disp, x)
-        xe = constrain(xe, ("batch", "experts", None, "embed"))
-        ye = experts(xe)
-        out = jnp.einsum("bsec,becd->bsd", combine, ye)
-    else:
-        raise ValueError(dispatch)
+    yk = jax.vmap(gather_row)(ye, e_idx, c_idx)  # (B, S, k, d)
+    out = jnp.einsum(
+        "bskd,bsk->bsd", yk, gate_w.astype(x.dtype) * keep_k.astype(x.dtype)
+    )
     out = constrain(out, ("batch", "seq_res", "embed"))
 
     # Switch-style load-balance loss + router z-loss. Each of the k picks

@@ -246,7 +246,6 @@ def _attn_block_apply(
     lp: dict,
     x: jax.Array,
     cfg: ModelConfig,
-    rt: RuntimeFlags,
     positions: jax.Array,
     rope_flag: Optional[jax.Array],
     window: int,
@@ -262,7 +261,7 @@ def _attn_block_apply(
         h = norm(x, lp, "attn_norm", cfg)
     with jax.named_scope("attention"):
         a, kv = attention_forward(
-            lp["attn"], h, cfg, rt, positions,
+            lp["attn"], h, cfg, positions,
             causal=True, window=window, rope_flag=rope_flag,
             mrope_positions=mrope_positions,
         )
@@ -271,7 +270,7 @@ def _attn_block_apply(
         h = norm(x, lp, "mlp_norm", cfg)
     if "moe" in lp:
         with jax.named_scope("moe"):
-            m, aux = moe_forward(lp["moe"], h, cfg, rt.moe_dispatch)
+            m, aux = moe_forward(lp["moe"], h, cfg)
     else:
         with jax.named_scope("mlp"):
             m, aux = mlp_forward(lp["mlp"], h, cfg), {}
@@ -282,7 +281,6 @@ def _attn_block_decode(
     lp: dict,
     x: jax.Array,  # (B, d)
     cfg: ModelConfig,
-    rt: RuntimeFlags,
     pos: jax.Array,  # (B,)
     cache_k, cache_v, cache_pos,
     rope_flag,
@@ -292,7 +290,7 @@ def _attn_block_decode(
         h = norm(x, lp, "attn_norm", cfg)
     with jax.named_scope("attention"):
         a, kv = decode_attention(
-            lp["attn"], h, cfg, rt, pos, cache_k, cache_v, cache_pos,
+            lp["attn"], h, cfg, pos, cache_k, cache_v, cache_pos,
             window=window, rope_flag=rope_flag,
         )
     x = x + a
@@ -300,7 +298,7 @@ def _attn_block_decode(
         h = norm(x, lp, "mlp_norm", cfg)
     if "moe" in lp:
         with jax.named_scope("moe"):
-            hm, aux = moe_forward(lp["moe"], h[:, None, :], cfg, rt.moe_dispatch)
+            hm, aux = moe_forward(lp["moe"], h[:, None, :], cfg)
         m = hm[:, 0]
     else:
         with jax.named_scope("mlp"):
@@ -323,7 +321,7 @@ def _uniform_stack(
     params, cfg, rt, x, positions, mrope_positions, collect_cache: bool
 ):
     flags = _iro_flags(cfg, cfg.n_layers)
-    window = rt.window_override or cfg.window
+    window = cfg.window
     aux0 = {"moe_lb_loss": jnp.float32(0.0), "moe_z_loss": jnp.float32(0.0)} \
         if cfg.n_experts else {}
 
@@ -333,8 +331,8 @@ def _uniform_stack(
         fl = None if flags is None else xs[1]
         fn = _attn_block_apply
         if rt.remat:
-            fn = jax.checkpoint(fn, static_argnums=(2, 3, 6))
-        x, kv, a = fn(lp, x, cfg, rt, positions, fl, window, mrope_positions)
+            fn = jax.checkpoint(fn, static_argnums=(2, 5))
+        x, kv, a = fn(lp, x, cfg, positions, fl, window, mrope_positions)
         aux = _sum_aux(dict(aux), a)
         ys = kv if collect_cache else None
         return (x, aux), ys
@@ -344,9 +342,9 @@ def _uniform_stack(
     return x, aux, kvs
 
 
-def _uniform_decode(params, cfg, rt, x, pos, cache):
+def _uniform_decode(params, cfg, x, pos, cache):
     flags = _iro_flags(cfg, cfg.n_layers)
-    window = rt.window_override or cfg.window
+    window = cfg.window
     Sc = cache["k"].shape[2]
     slot = pos % Sc  # ring-buffer slot (full cache: pos < Sc)
     bidx = jnp.arange(x.shape[0])
@@ -358,7 +356,7 @@ def _uniform_decode(params, cfg, rt, x, pos, cache):
         else:
             lp, ck, cv, fl = xs
         x, (kn, vn), _ = _attn_block_decode(
-            lp, x, cfg, rt, pos, ck, cv, cache["pos"], fl, window
+            lp, x, cfg, pos, ck, cv, cache["pos"], fl, window
         )
         ck = ck.at[bidx, slot].set(kn)
         cv = cv.at[bidx, slot].set(vn)
@@ -379,12 +377,12 @@ def _uniform_decode(params, cfg, rt, x, pos, cache):
 
 def _hybrid_stack(params, cfg, rt, x, positions, collect_cache: bool):
     ng, gs, rem = _group_shape(cfg)
-    window = rt.window_override or cfg.window
+    window = cfg.window
 
     def mamba_layer(carry, lp):
         x = carry
         h = norm(x, lp, "norm", cfg)
-        y, st = mamba2_forward(lp["mamba"], h, cfg, chunk=rt.mamba_chunk)
+        y, st = mamba2_forward(lp["mamba"], h, cfg)
         ys = st if collect_cache else None
         return x + y, ys
 
@@ -392,7 +390,7 @@ def _hybrid_stack(params, cfg, rt, x, positions, collect_cache: bool):
         x, _aux = carry
         x, sts = jax.lax.scan(mamba_layer, x, glp)
         x, kv, a = _attn_block_apply(
-            params["shared"], x, cfg, rt, positions, None, window
+            params["shared"], x, cfg, positions, None, window
         )
         return (x, _sum_aux(dict(_aux), a)), (sts, kv if collect_cache else None)
 
@@ -408,9 +406,9 @@ def _hybrid_stack(params, cfg, rt, x, positions, collect_cache: bool):
     return x, aux, (mamba_states, rest_states, kvs)
 
 
-def _hybrid_decode(params, cfg, rt, x, pos, cache):
+def _hybrid_decode(params, cfg, x, pos, cache):
     ng, gs, rem = _group_shape(cfg)
-    window = rt.window_override or cfg.window
+    window = cfg.window
     Sc = cache["k"].shape[2]
     slot = pos % Sc
     bidx = jnp.arange(x.shape[0])
@@ -427,7 +425,7 @@ def _hybrid_decode(params, cfg, rt, x, pos, cache):
         glp, gst, ck, cv = xs
         x, st_new = jax.lax.scan(mamba_layer, x, (glp, gst))
         x, (kn, vn), _ = _attn_block_decode(
-            params["shared"], x, cfg, rt, pos, ck, cv, cache["pos"], None, window
+            params["shared"], x, cfg, pos, ck, cv, cache["pos"], None, window
         )
         ck = ck.at[bidx, slot].set(kn)
         cv = cv.at[bidx, slot].set(vn)
@@ -455,7 +453,7 @@ def _ssm_stack(params, cfg, rt, x, collect_cache: bool):
     def mlstm_layer(carry, lp):
         x = carry
         h = norm(x, lp, "norm", cfg)
-        y, st = mlstm_forward(lp["mlstm"], h, cfg, chunk=rt.mlstm_chunk)
+        y, st = mlstm_forward(lp["mlstm"], h, cfg)
         return x + y, st if collect_cache else None
 
     def group_body(carry, xs):
@@ -475,7 +473,7 @@ def _ssm_stack(params, cfg, rt, x, collect_cache: bool):
     return x, {}, (mstates, sstates)
 
 
-def _ssm_decode(params, cfg, rt, x, cache):
+def _ssm_decode(params, cfg, x, cache):
     def mlstm_layer(carry, xs):
         x = carry
         lp, st = xs
@@ -618,7 +616,6 @@ def decoder_prefill(
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     x = embed_inputs(params, cfg, inputs)
-    window = rt.window_override or cfg.window
 
     if cfg.family in ("dense", "vlm", "moe"):
         x, aux, kvs = _uniform_stack(
@@ -647,7 +644,6 @@ def decoder_prefill(
 def decoder_decode(
     params: dict,
     cfg: ModelConfig,
-    rt: RuntimeFlags,
     cache: dict,
     token: jax.Array,  # (B,) int tokens or (B, d) embeds
     pos: jax.Array,  # (B,)
@@ -659,11 +655,11 @@ def decoder_decode(
         x = jnp.take(params["embed"], token, axis=0)
     x = constrain(x, ("batch", "embed"))
     if cfg.family in ("dense", "vlm", "moe"):
-        x, cache = _uniform_decode(params, cfg, rt, x, pos, cache)
+        x, cache = _uniform_decode(params, cfg, x, pos, cache)
     elif cfg.family == "hybrid":
-        x, cache = _hybrid_decode(params, cfg, rt, x, pos, cache)
+        x, cache = _hybrid_decode(params, cfg, x, pos, cache)
     elif cfg.family == "ssm":
-        x, cache = _ssm_decode(params, cfg, rt, x, cache)
+        x, cache = _ssm_decode(params, cfg, x, cache)
     else:
         raise ValueError(cfg.family)
     return logits_from_hidden(params, cfg, x), cache

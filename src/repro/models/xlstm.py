@@ -25,7 +25,7 @@ FFN); sLSTM blocks are pre-up-projection (cell at d_model, then a gated
 from __future__ import annotations
 
 import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +33,9 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from ..sharding import constrain
 from .common import Initializer, rms_norm
+
+# steps per chunk of the chunkwise mLSTM forward
+CHUNK = 256
 
 __all__ = [
     "init_mlstm",
@@ -103,12 +106,12 @@ def mlstm_forward(
     p: dict,
     x: jax.Array,  # (B, S, d)
     cfg: ModelConfig,
-    chunk: int = 256,
+    chunk: Optional[int] = None,  # None: CHUNK
     state: dict = None,
 ) -> Tuple[jax.Array, dict]:
     B, S, _ = x.shape
     nh, P, di = cfg.n_heads, cfg.d_inner // cfg.n_heads, cfg.d_inner
-    Q = min(chunk, S)
+    Q = min(chunk or CHUNK, S)
     pad = (-S) % Q
     prior = state or {}
 

@@ -84,16 +84,6 @@ TRAIN_RULES: AxisRules = {
 # Hillclimbed train rules: + sequence-parallel residual stream.
 TRAIN_RULES_SP: AxisRules = dict(TRAIN_RULES, seq_res=("model",))
 
-# Context-parallel attention (RuntimeFlags.attn_seq_shard): the attention
-# core shards by query sequence — for archs whose head count does not
-# divide the model axis.
-TRAIN_RULES_ATTNSP: AxisRules = dict(TRAIN_RULES, attn_q_seq=("model",))
-
-# Context-parallel attention + sequence-parallel residual combined.
-TRAIN_RULES_CP_SP: AxisRules = dict(
-    TRAIN_RULES, attn_q_seq=("model",), seq_res=("model",)
-)
-
 # Pure-FSDP training (ZeRO-3 style): batch shards over the WHOLE mesh, no
 # tensor parallelism; every parameter shards 256-way along its embed dim and
 # is all-gathered per layer. For models whose per-layer weights are smaller
@@ -108,15 +98,14 @@ TRAIN_RULES_FSDP: AxisRules = {
     "p_inner": (), "p_experts": (),
 }
 
-# Expert-parallel MoE + context-parallel attention: experts live one-per-
-# model-rank (all-to-all dispatch), attention shards by query sequence,
-# batch is data-parallel only. The canonical MoE sharding for archs whose
-# expert count matches the model axis (llama4-scout: 16 experts).
+# Expert-parallel MoE: experts live one-per-model-rank (all-to-all
+# dispatch), heads and ffn are not tensor-parallel, batch is data-parallel
+# only. The canonical MoE sharding for archs whose expert count matches
+# the model axis (llama4-scout: 16 experts).
 TRAIN_RULES_EP_CP: AxisRules = {
     **TRAIN_RULES,
     "experts": ("model",),
     "p_experts": ("model",),
-    "attn_q_seq": ("model",),
     "heads": (), "kv_heads": (), "ffn": (),
     "p_heads": (), "p_kv_heads": (), "p_ffn": (),
 }

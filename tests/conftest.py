@@ -24,19 +24,27 @@ ASSIGNED_ARCHS = [
     "llama4-scout-17b-a16e",
 ]
 
+# Smoke sequences are a few tokens long: scan chunks of 4 make every
+# model-level zamba2 and xLSTM test cross chunk boundaries. Set once, before
+# any model is traced, so no jitted program sees two values.
+from repro.models import mamba2, xlstm  # noqa: E402
+
+mamba2.CHUNK = xlstm.CHUNK = 4
+
 _model_cache = {}
 
 
-def smoke_model(name: str, **rt_kw):
-    """Session-cached (model, params) for a smoke config in float32."""
+def smoke_model(name: str, **overrides):
+    """(model, params, axes) for a smoke config in float32, built once per
+    test process, with `overrides` replacing config fields (e.g. window=8)."""
     from repro.configs import get_config
     from repro.models import RuntimeFlags, build_model
 
-    rt = RuntimeFlags(remat=False, mamba_chunk=4, mlstm_chunk=4, **rt_kw)
-    key = (name, tuple(sorted(rt_kw.items())))
+    key = (name, tuple(sorted(overrides.items())))
     if key not in _model_cache:
-        cfg = dataclasses.replace(get_config(name, smoke=True), dtype="float32")
-        model = build_model(cfg, rt)
+        cfg = dataclasses.replace(get_config(name, smoke=True),
+                                  dtype="float32", **overrides)
+        model = build_model(cfg, RuntimeFlags(remat=False))
         params, axes = model.init(jax.random.PRNGKey(0))
         _model_cache[key] = (model, params, axes)
     return _model_cache[key]

@@ -12,14 +12,14 @@ pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_config
-from repro.models.common import Initializer
+from repro.models.common import Initializer, activation_fn
 from repro.models.mamba2 import (
     init_mamba2,
     init_mamba_state,
     mamba2_decode_step,
     mamba2_forward,
 )
-from repro.models.moe import expert_capacity, init_moe, moe_forward
+from repro.models.moe import _route, expert_capacity, init_moe, moe_forward
 from repro.models.xlstm import (
     init_mlstm,
     init_mlstm_state,
@@ -307,8 +307,33 @@ class TestCheckpoint:
             restore_checkpoint(str(tmp_path), bad)
 
 
+def moe_reference(p, x, cfg):
+    """The Mesh-TF one-hot form of `moe_forward`: the (B, S, E, C) combine
+    tensor carries the router weights, dispatch is its boolean support, and
+    both move tokens by einsum. -> (out, {"moe_lb_loss"})."""
+    k = cfg.top_k
+    C = expert_capacity(cfg, x.shape[1])
+    act = activation_fn(cfg.activation)
+    gate_w, _, pos_sel, keep_k, sel, keep, probs, _ = _route(p, x, cfg, C)
+    e_oh = (sel * keep).astype(x.dtype) * gate_w[..., None].astype(x.dtype)
+    c_oh = jax.nn.one_hot(jnp.where(keep_k, pos_sel, C), C, dtype=x.dtype)
+    combine = jnp.einsum("bske,bskc->bsec", e_oh, c_oh)
+    xe = jnp.einsum("bsec,bsd->becd", (combine > 0).astype(x.dtype), x)
+    h = jnp.einsum("becd,edf->becf", xe, p["w1"])
+    if "w3" in p:
+        h = act(h) * jnp.einsum("becd,edf->becf", xe, p["w3"])
+    else:
+        h = act(h)
+    ye = jnp.einsum("becf,efd->becd", h, p["w2"])
+    out = jnp.einsum("bsec,becd->bsd", combine, ye)
+    frac_tokens = jnp.mean(sel.astype(jnp.float32).sum(2), axis=(0, 1)) / k
+    frac_prob = jnp.mean(probs, axis=(0, 1))
+    return out, {"moe_lb_loss": cfg.n_experts * jnp.sum(frac_tokens * frac_prob)}
+
+
 class TestMoEDispatchEquivalence:
-    """scatter (optimized) == einsum (Mesh-TF baseline), fwd and grad."""
+    """moe_forward (scatter/gather) == moe_reference (one-hot einsum),
+    fwd and grad."""
 
     def _setup(self, name):
         cfg = dataclasses.replace(get_config(name, smoke=True), dtype="float32")
@@ -319,8 +344,8 @@ class TestMoEDispatchEquivalence:
     @pytest.mark.parametrize("name", ["mixtral-8x22b", "llama4-scout-17b-a16e"])
     def test_forward_equal(self, name):
         cfg, p, x = self._setup(name)
-        y_e, aux_e = moe_forward(p, x, cfg, dispatch="einsum")
-        y_s, aux_s = moe_forward(p, x, cfg, dispatch="scatter")
+        y_e, aux_e = moe_reference(p, x, cfg)
+        y_s, aux_s = moe_forward(p, x, cfg)
         np.testing.assert_allclose(
             np.asarray(y_e), np.asarray(y_s), rtol=2e-4, atol=2e-4
         )
@@ -330,8 +355,8 @@ class TestMoEDispatchEquivalence:
 
     def test_grads_close(self):
         cfg, p, x = self._setup("mixtral-8x22b")
-        gs = jax.grad(lambda q: moe_forward(q, x, cfg, "scatter")[0].sum())(p)
-        ge = jax.grad(lambda q: moe_forward(q, x, cfg, "einsum")[0].sum())(p)
+        gs = jax.grad(lambda q: moe_forward(q, x, cfg)[0].sum())(p)
+        ge = jax.grad(lambda q: moe_reference(q, x, cfg)[0].sum())(p)
         for a, b in zip(jax.tree.leaves(gs), jax.tree.leaves(ge)):
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=2e-2, atol=2e-2
@@ -341,8 +366,8 @@ class TestMoEDispatchEquivalence:
         """Both dispatches drop the same tokens under tight capacity."""
         cfg, p, x = self._setup("mixtral-8x22b")
         tight = dataclasses.replace(cfg, capacity_factor=0.5)
-        y_e, _ = moe_forward(p, x, tight, dispatch="einsum")
-        y_s, _ = moe_forward(p, x, tight, dispatch="scatter")
+        y_e, _ = moe_reference(p, x, tight)
+        y_s, _ = moe_forward(p, x, tight)
         np.testing.assert_allclose(
             np.asarray(y_e), np.asarray(y_s), rtol=2e-4, atol=2e-4
         )

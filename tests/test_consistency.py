@@ -60,9 +60,9 @@ def test_decode_matches_forward(arch_name):
 
 
 def test_sliding_window_ring_cache():
-    """Dense arch forced into the long_500k window variant: decode with a
-    ring cache must equal full forward with the same window mask."""
-    model, params, _ = smoke_model("glm4-9b", window_override=8)
+    """Dense arch given the long_500k window variant: decode with a ring
+    cache must equal full forward with the same window mask."""
+    model, params, _ = smoke_model("glm4-9b", window=8)
     W = 8
     toks = jax.random.randint(jax.random.PRNGKey(9), (B, 20), 0, 1024)
     logits_full, _ = model.forward(params, toks)  # window applied in-stack

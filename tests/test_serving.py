@@ -29,8 +29,7 @@ _CACHE = {}
 def model_params(name="llama2-7b"):
     if name not in _CACHE:
         cfg = dataclasses.replace(get_config(name, smoke=True), dtype="float32")
-        m = build_model(cfg, RuntimeFlags(remat=False, mamba_chunk=4,
-                                          mlstm_chunk=4))
+        m = build_model(cfg, RuntimeFlags(remat=False))
         p, _ = m.init(jax.random.PRNGKey(0))
         _CACHE[name] = (m, p)
     return _CACHE[name]
