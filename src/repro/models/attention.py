@@ -12,6 +12,11 @@ of keys alone (`core_for_keys`):
 Masking supports causal, sliding-window (Mixtral/long_500k serving variant)
 and full (encoder / cross attention). GQA is native: q is shaped
 (B, S, K, G, dh) against KV (B, S, K, dh).
+
+Serving packs each self-attention block's Q/K/V weights into one 2-D leaf
+(`pack_self_attention`): a (d, N) weight is read by one dot as the MLP's
+are, where the 3-D (d, heads, dh) weights are each staged through a copy
+before their dot. `_project_qkv` takes whichever layout it is given.
 """
 
 from __future__ import annotations
@@ -23,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from ..sharding import constrain
+from ..sharding import constrain, shard_count
 from .common import Initializer
 from .rope import apply_mrope, apply_rope, text_mrope_positions
 
@@ -33,6 +38,8 @@ __all__ = [
     "decode_attention",
     "attention_core",
     "core_for_keys",
+    "pack_self_attention",
+    "packed_blocks",
 ]
 
 NEG_INF = -1e30
@@ -56,6 +63,72 @@ def init_attention(init: Initializer, cfg: ModelConfig) -> dict:
         p["bk"] = init.param("bk", (K, dh), ("p_kv_heads", None), zeros=True)
         p["bv"] = init.param("bv", (K, dh), ("p_kv_heads", None), zeros=True)
     return p
+
+
+# ---------------------------------------------------------------------------
+# the serving layout: Q, K and V packed into one matrix
+# ---------------------------------------------------------------------------
+
+# param keys of self-attention blocks; "cross_attn" projects its K/V from
+# the encoder memory (`project_kv`), so it keeps separate projections
+SELF_ATTENTION = ("attn", "self_attn")
+
+
+@jax.jit
+def _pack_heads(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+    """q (..., H, dh), k and v (..., K, dh) -> (..., K * (H/K + 2) * dh):
+    for each KV head its H/K query heads (h = k * G + g), then its K, then
+    its V."""
+    *lead, H, dh = q.shape
+    K = k.shape[-2]
+    qkv = jnp.concatenate(
+        [q.reshape(*lead, K, H // K, dh), k[..., None, :], v[..., None, :]],
+        axis=-2,
+    )
+    return qkv.reshape(*lead, K * (H // K + 2) * dh)
+
+
+def _pack_block(p: dict) -> dict:
+    """One self-attention block (leading layer axes kept) with wq, wk, wv
+    replaced by wqkv (..., d, K * (G + 2) * dh), and bq, bk, bv by bqkv.
+    Left as it is where already packed, or where the active mesh would
+    split the packed columns inside a KV head's group."""
+    if "wq" not in p:
+        return p
+    *_, H, dh = p["wq"].shape
+    K = p["wk"].shape[-2]
+    if K % shard_count(K * (H // K + 2) * dh, "p_kv_heads"):
+        return p
+    out = {k: v for k, v in p.items() if k not in ("wq", "wk", "wv")}
+    out["wqkv"] = _pack_heads(p["wq"], p["wk"], p["wv"])
+    if "bq" in p:
+        for b in ("bq", "bk", "bv"):
+            del out[b]
+        out["bqkv"] = _pack_heads(p["bq"], p["bk"], p["bv"])
+    return out
+
+
+def pack_self_attention(params: dict) -> dict:
+    """The params with every self-attention block packed (`_pack_block`).
+    Every other leaf is the input's own array, not a copy."""
+
+    def walk(t):
+        if not isinstance(t, dict):
+            return t
+        return {k: _pack_block(v) if k in SELF_ATTENTION else walk(v)
+                for k, v in t.items()}
+
+    return walk(params)
+
+
+def packed_blocks(params: dict) -> int:
+    """How many attention blocks hold their Q, K and V packed: a stacked
+    leaf counts each of its layers."""
+    if not isinstance(params, dict):
+        return 0
+    if "wqkv" in params:
+        return math.prod(params["wqkv"].shape[:-2])
+    return sum(packed_blocks(v) for v in params.values())
 
 
 # ---------------------------------------------------------------------------
@@ -199,13 +272,29 @@ def _project_qkv(
     mrope_positions: Optional[jax.Array] = None,  # (3, B, S)
     rope_flag: Optional[jax.Array] = None,  # traced scalar: 1=RoPE, 0=NoPE (iRoPE)
 ):
-    q = jnp.einsum("bsd,dkh->bskh", x, p["wq"].reshape(cfg.d_model, -1, cfg.head_dim))
-    k = jnp.einsum("bsd,dkh->bskh", x, p["wk"])
-    v = jnp.einsum("bsd,dkh->bskh", x, p["wv"])
-    if cfg.qkv_bias:
-        q = q + p["bq"].reshape(-1, cfg.head_dim)
-        k = k + p["bk"]
-        v = v + p["bv"]
+    if "wqkv" in p:  # packed (`pack_self_attention`): one dot
+        B, S, _ = x.shape
+        K, G = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+        qkv = jnp.einsum("bsd,dn->bsn", x, p["wqkv"])
+        if cfg.qkv_bias:
+            qkv = qkv + p["bqkv"]
+        # The barrier keeps the dot's output in its own (b, s, n) layout.
+        # Fused with the reshape below, the TPU compiler lays it out head
+        # major and then copies the whole weight, transposed, before the
+        # dot on every layer.
+        qkv = jax.lax.optimization_barrier(qkv)
+        qkv = qkv.reshape(B, S, K, G + 2, cfg.head_dim)
+        q = qkv[:, :, :, :G].reshape(B, S, K * G, cfg.head_dim)
+        k, v = qkv[:, :, :, G], qkv[:, :, :, G + 1]
+    else:
+        q = jnp.einsum("bsd,dkh->bskh", x,
+                       p["wq"].reshape(cfg.d_model, -1, cfg.head_dim))
+        k = jnp.einsum("bsd,dkh->bskh", x, p["wk"])
+        v = jnp.einsum("bsd,dkh->bskh", x, p["wv"])
+        if cfg.qkv_bias:
+            q = q + p["bq"].reshape(-1, cfg.head_dim)
+            k = k + p["bk"]
+            v = v + p["bv"]
     if positions is not None:
         with jax.named_scope("rope"):
             if cfg.mrope_sections:

@@ -31,7 +31,8 @@ def measure_service_time(
     max_seq: int = 256,
     repeats: int = 3,
 ) -> Dict[str, float]:
-    """Time prefill + n_output decode steps at batch 1. Returns seconds."""
+    """Time prefill + n_output decode steps at batch 1. Returns seconds.
+    One engine, reset between repeats, so one packed copy of the params."""
     eng = InferenceEngine(model, params, max_batch=1, max_seq=max_seq)
     key = jax.random.PRNGKey(0)
     prompt = jax.random.randint(key, (n_input,), 0, model.cfg.vocab_size)
@@ -39,8 +40,8 @@ def measure_service_time(
     eng.generate([GenRequest(uid=-1, prompt=prompt, max_new_tokens=n_output)])
     prefill_s, decode_s = [], []
     for r in range(repeats):
-        eng2 = InferenceEngine(model, params, max_batch=1, max_seq=max_seq)
-        res = eng2.generate(
+        eng.reset()
+        res = eng.generate(
             [GenRequest(uid=r, prompt=prompt, max_new_tokens=n_output)]
         )[r]
         prefill_s.append(res.prefill_s)

@@ -32,6 +32,13 @@ one wait for both, `submit` reads the first token once. A request that
 samples draws its first token with `sample_token` from the prefill's
 logits instead (`EngineCounters.sampled_prefills`).
 
+The engine serves a copy of the params in which each self-attention
+block's Q/K/V weights are packed into one matrix
+(`models.attention.pack_self_attention`), made when the params are set:
+the prefill and the decode tick then project Q, K and V with one dot that
+streams its weight as the MLP's do. Every other leaf is the caller's own
+array. `packed_attn_blocks` counts the blocks packed.
+
 Slot splicing is generic across cache families (attention KV, Mamba/xLSTM
 states, enc-dec cross KV): the logical-axes tree from `model.init_cache`
 marks each leaf's batch dim ("kv_batch"), so insertion is a
@@ -50,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from ..models.attention import pack_self_attention, packed_blocks
 from ..models.model import Model
 
 __all__ = [
@@ -184,7 +192,7 @@ class InferenceEngine:
         enc_len: int = 0,
     ):
         self.model = model
-        self.params = params
+        self.params = params  # packed by the setter
         self.M = max_batch
         self.Sc = max_seq
         self._enc_len = enc_len
@@ -202,6 +210,23 @@ class InferenceEngine:
         self._splice_slot = functools.partial(
             splice_slot, batch_axes=tuple(jax.tree.leaves(batch_axes))
         )
+
+    @property
+    def params(self) -> dict:
+        """The params the engine serves: the caller's, with each
+        self-attention block's Q, K and V packed into one matrix."""
+        return self._params
+
+    @params.setter
+    def params(self, params: Optional[dict]) -> None:
+        self._params = None  # the old packed copy goes before the new one
+        if params is not None:
+            self._params = pack_self_attention(params)
+
+    @property
+    def packed_attn_blocks(self) -> int:
+        """Attention blocks whose Q, K and V the served params hold packed."""
+        return packed_blocks(self._params)
 
     # ------------------------------------------------------------- slots
     def _clear_slots(self) -> None:

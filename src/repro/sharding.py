@@ -39,6 +39,7 @@ __all__ = [
     "current_mesh",
     "spec_for",
     "sharding_for",
+    "shard_count",
     "constrain",
     "tree_specs",
     "Axes",
@@ -214,6 +215,18 @@ def sharding_for(shape: Sequence[int], axes: Sequence[Optional[str]]) -> Optiona
     if ctx is None:
         return None
     return NamedSharding(ctx.mesh, spec_for(shape, axes))
+
+
+def shard_count(dim: int, name: str) -> int:
+    """How many shards the active rules split a dim of size `dim` with
+    logical name `name` into: 1 outside a mesh or where it is replicated."""
+    ctx = _ctx.get()
+    if ctx is None:
+        return 1
+    ax = _resolve_dim(dim, name, ctx, set())
+    if ax is None:
+        return 1
+    return math.prod(ctx.mesh.shape[a] for a in (ax if isinstance(ax, tuple) else (ax,)))
 
 
 def constrain(x: jax.Array, axes: Sequence[Optional[str]]) -> jax.Array:

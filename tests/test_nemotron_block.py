@@ -16,7 +16,7 @@ from repro.configs import get_config
 from repro.models import RuntimeFlags, build_model
 from repro.models.common import norm
 from repro.models.rope import apply_rope
-from repro.serving import GenRequest, InferenceEngine
+from repro.serving import GenRequest, InferenceEngine, engine
 from repro.serving.engine import decode_step
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
@@ -76,11 +76,14 @@ def test_partial_rope_matches_transformers():
     np.testing.assert_array_equal(np.asarray(got)[..., 64:], q[..., 64:])
 
 
-def test_glm4_smoke_logits_are_unchanged():
+def test_glm4_smoke_logits_are_unchanged(monkeypatch):
     """glm4-9b keeps the default fields (RMSNorm, RoPE over the whole
     head), so the engine serves it the logits it served before the fields
     existed: prefill, then two steps of decode_step through the cache.
-    The recorded logits were written by the program before the change."""
+    The recorded logits were written by the program before the change,
+    from the unpacked Q/K/V projections; the engine is held to them on
+    those (the packed layout it serves sums the same products in another
+    order, and tests/test_packed_qkv.py holds it to this path)."""
     with open(os.path.join(DATA, "glm4_smoke_logits.json")) as f:
         rec = json.load(f)
     cfg = dataclasses.replace(get_config("glm4-9b", smoke=True), dtype="float32")
@@ -89,7 +92,10 @@ def test_glm4_smoke_logits_are_unchanged():
     params, _ = model.init(jax.random.PRNGKey(0))
     prompt = jnp.asarray(np.random.default_rng(0).integers(
         0, cfg.vocab_size, 7, dtype=np.int32))
-    res = InferenceEngine(model, params, max_batch=2, max_seq=16).generate(
+    monkeypatch.setattr(engine, "pack_self_attention", lambda p: p)
+    eng = InferenceEngine(model, params, max_batch=2, max_seq=16)
+    assert eng.packed_attn_blocks == 0
+    res = eng.generate(
         [GenRequest(uid=0, prompt=prompt, max_new_tokens=3, keep_logits=True)])[0]
     assert res.tokens == rec["tokens"]
     np.testing.assert_allclose(np.stack([np.asarray(x) for x in res.logits]),
